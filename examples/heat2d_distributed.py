@@ -24,11 +24,11 @@ import numpy as np
 from repro.core.reference import stencil_reference_np
 from repro.core.spec import heat_2d
 from repro.distributed.halo import distributed_stencil2d, halo_bytes_per_step
-from repro.distributed.sharding import make_mesh_compat
 
 
 def main():
-    mesh = make_mesh_compat((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     fuse_t = 4
     spec = dataclasses.replace(heat_2d(256, 512, alpha=0.12), timesteps=fuse_t)
     step = distributed_stencil2d(spec, mesh, axes=("pod", "data"))

@@ -40,8 +40,7 @@ def main():
     blocks = plan_2d_blocks(449, 960, 12, 12, timesteps=1)
     xf = jnp.asarray(np.random.default_rng(1).normal(size=(1, 449, 960)),
                      jnp.float32)
-    y = stencil2d(xf, spec32.coeffs[0], spec32.coeffs[1], backend="pallas",
-                  block=(min(blocks[0], 64), min(blocks[1], 256)))
+    y = stencil2d(xf, spec32.coeffs[0], spec32.coeffs[1], backend="pallas")
     ref = stencil_reference_np(np.asarray(xf[0]),
                                dataclasses.replace(spec32))
     print(f"[pallas] blocks={blocks} max err={np.abs(np.asarray(y[0])-ref).max():.2e}")

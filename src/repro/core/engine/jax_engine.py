@@ -45,33 +45,26 @@ for those; the tuner routes stage-2 finalists through the vector engine.
 
 Determinism: everything is integer except the memory credit, which must be
 float64 (``elems_per_cycle`` ≈ 10.41̅6 on the paper CGRA).  The module
-evaluates under ``jax.experimental.enable_x64`` so the credit walk is
+evaluates under ``jax.enable_x64(True)`` so the credit walk is
 bit-identical to the other engines' python-float walk: for f64 ``x >= 1``,
 ``x - 1.0`` is exact, hence subtracting the fired count equals the
-interpreter's repeated ``-= 1.0``.  Pin ``JAX_PLATFORMS=cpu`` for
-cross-machine reproducibility in CI (ci.sh does).
+interpreter's repeated ``-= 1.0``.
 """
 from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.engine.common import RawStats, SimDeadlock
-from repro.core.engine.compile import (CompiledPlan, _keep_array,
-                                       compiled_for)
+from repro.core.engine.compile import (CompiledPlan, StaleCompiledPlanError,
+                                       _keep_array, compiled_for)
 from repro.telemetry.probe import (ST_INACTIVE, ST_INPUT_STARVED, ST_MEM_ARB,
                                    ST_OUTPUT_BLOCKED, format_stall_summary,
                                    summary_from_state)
-
-try:                                        # gate, don't hard-require:
-    import jax                              # the rest of repro.core works
-    import jax.numpy as jnp                 # without jax installed
-    from jax import lax
-    _JAX_ERR = None
-except Exception as _e:                     # pragma: no cover - env-specific
-    jax = jnp = lax = None
-    _JAX_ERR = _e
 
 __all__ = ["SEMANTICS", "JaxLoweringError", "run", "run_compiled_batch"]
 
@@ -93,13 +86,6 @@ class JaxLoweringError(NotImplementedError):
     mode, telemetry, or a shape the padding can't absorb).  Callers that
     batch (the tuner) catch this per lane and fall back to the vector
     engine."""
-
-
-def _require_jax() -> None:
-    if jax is None:                        # pragma: no cover - env-specific
-        raise JaxLoweringError(
-            f"engine='jax' needs the jax package (import failed: {_JAX_ERR!r})"
-            "; use engine='vector'")
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -169,7 +155,6 @@ def shared_dims(cps: list[CompiledPlan]) -> tuple:
 def lower(cp: CompiledPlan, dims: tuple | None = None) -> LoweredPlan:
     """Lower one compiled plan into padded pure-array tables (see the
     module docstring for the sentinel/padding rules)."""
-    _require_jax()
     if cp.net is not None:
         raise JaxLoweringError(
             "engine='jax' is ideal-mode only (no network-aware simulation); "
@@ -398,17 +383,11 @@ def _run_single(t: dict, max_cycles):
     return lax.while_loop(cond, lambda c: _cycle_step(t, c), carry0)
 
 
-_sweep_fn = None
-
-
+@jax.jit
 def _sweep(stacked: dict, max_cycles):
     """Jitted vmap of the fixed-point loop; cached per padded-shape bucket
     by jax's own jit cache."""
-    global _sweep_fn
-    if _sweep_fn is None:
-        _sweep_fn = jax.jit(
-            lambda s, mc: jax.vmap(lambda t: _run_single(t, mc))(s))
-    return _sweep_fn(stacked, max_cycles)
+    return jax.vmap(lambda t: _run_single(t, max_cycles))(stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -578,7 +557,6 @@ def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
     written back), a ``SimDeadlock`` *value* (not raised) for lanes that
     deadlock or time out, or a ``JaxLoweringError`` value for lanes the
     lowering rejects — one bad lane never poisons its siblings."""
-    _require_jax()
     max_cycles = min(int(max_cycles), (1 << 31) - 2)   # int32 cycle counter
     results: list = [None] * len(items)
     good: list[tuple[int, CompiledPlan, float]] = []
@@ -592,7 +570,7 @@ def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
             good.append((i, cp, float(epc)))
         except JaxLoweringError as e:
             results[i] = e
-        except Exception as e:
+        except StaleCompiledPlanError as e:
             results[i] = JaxLoweringError(str(e))
     if not good:
         return results
@@ -602,7 +580,7 @@ def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
     # sorting clusters similar-length lanes into the same lockstep group
     good.sort(key=lambda t: t[1].n_nodes)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for g0 in range(0, len(good), _GROUP):
             grp = good[g0:g0 + _GROUP]
             dims = shared_dims([cp for _, cp, _ in grp])
@@ -637,7 +615,6 @@ def run(plan, flat_in, flat_out, elems_per_cycle: float,
     """Single-plan entry with the same signature/contract as
     ``interp.run``/``vector.run`` (a batch of one; the jit cache makes the
     padded-shape bucket warm across calls).  Ideal mode only."""
-    _require_jax()
     if fabric is not None:
         raise NotImplementedError(
             "engine='jax' does not simulate routed fabrics; use "

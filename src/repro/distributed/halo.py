@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.spec import StencilSpec
-from repro.distributed.sharding import shard_map_compat
 
 
 # --------------------------------------------------------------------------
@@ -179,7 +178,7 @@ def distributed_stencil1d(spec: StencilSpec, mesh: Mesh, axis: str = "data"):
         "shard smaller than halo; reduce timesteps or shards"
     pspec = P(axis)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_local_stencil1d, spec=spec, axis_name=axis),
         mesh=mesh, in_specs=pspec, out_specs=pspec)
     return jax.jit(fn, in_shardings=NamedSharding(mesh, pspec),
@@ -196,7 +195,7 @@ def distributed_stencil2d(spec: StencilSpec, mesh: Mesh,
     assert nx // sx >= spec.radii[1] * spec.timesteps
     pspec = P(axes[0], axes[1])
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_local_stencil2d, spec=spec, ax_names=axes),
         mesh=mesh, in_specs=pspec, out_specs=pspec)
     return jax.jit(fn, in_shardings=NamedSharding(mesh, pspec),
@@ -213,7 +212,7 @@ def distributed_stencil3d(spec: StencilSpec, mesh: Mesh,
     assert ny // sy >= spec.radii[1] * spec.timesteps
     pspec = P(axes[0], axes[1], None)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_local_stencil3d, spec=spec, ax_names=axes),
         mesh=mesh, in_specs=pspec, out_specs=pspec)
     return jax.jit(fn, in_shardings=NamedSharding(mesh, pspec),

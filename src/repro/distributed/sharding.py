@@ -49,33 +49,6 @@ DEFAULT_RULES: Rules = {
 INFERENCE_RULES: Rules = {**DEFAULT_RULES, "fsdp": [()]}
 
 
-def make_mesh_compat(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis_types where the installed
-    jax supports them (``jax.sharding.AxisType`` is newer than 0.4.x); older
-    jax treats every axis as Auto already, so plain make_mesh is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` under its pre-promotion spelling when needed."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def mesh_context(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.sharding.set_mesh`` where it exists, else the Mesh object itself
-    (which has been a context manager since the pjit days)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def _axes_size(mesh: Mesh, axes: tuple[str, ...]) -> int:
     return math.prod(mesh.shape[a] for a in axes)
 

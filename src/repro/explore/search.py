@@ -118,6 +118,7 @@ class _BudgetState:
         self.budget = budget
         self.evals = 0
         self.sim_cycles = 0
+        self.host_fallbacks = 0           # batched lanes rerouted to the host
 
     def exhausted(self) -> bool:
         b = self.budget
@@ -421,7 +422,9 @@ def _stage1_batched(target, kept, machine, *, base_scope: dict,
         for (cfg, key, plan, x, t0), res in zip(lanes, raw):
             if isinstance(res, NotImplementedError):
                 # lowering rejected this lane: sequential fallback, measured
-                # and cached under the sequential engine's own scope
+                # and cached under the sequential engine's own scope, and
+                # counted so a device sweep cannot quietly run on the host
+                state.host_fallbacks += 1
                 pt = _evaluate(target, cfg, machine, scope=seq_scope,
                                cache=cache, state=state, engine=engine,
                                failures=failures, skipped=skipped,
@@ -599,6 +602,7 @@ def explore(target, machine: Machine, *,
         "n_kept": len(kept), "n_measured": state.evals,
         "n_cached": cache.hits, "n_failures": len(failures),
         "n_budget_skipped": len(skipped),
+        "n_host_fallback": state.host_fallbacks,
         "static_pruned": sum(1 for f in failures
                              if f["reason"].startswith("static-")),
         "sim_cycles_total": state.sim_cycles,

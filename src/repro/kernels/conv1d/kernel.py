@@ -16,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _body(prev, cur, wref, o, *, kk, block_s, out_dtype):
@@ -58,6 +58,6 @@ def conv1d_pallas(x: jax.Array, w: jax.Array, *, block_s: int = 256,
         out_specs=pl.BlockSpec((1, block_s, block_c),
                                lambda i, si, ci: (i, si, ci)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret)(x, x, w)

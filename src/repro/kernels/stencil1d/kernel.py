@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ext_positions(j, block_n: int, halo: int):
@@ -137,7 +137,7 @@ def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
     ]
     out_spec = pl.BlockSpec((block_b, block_n), lambda i, j: (i, j))
     out_shape = jax.ShapeDtypeStruct((b, n), x.dtype)
-    params = tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
 
     if variant == "vpu":

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _sweep2d(ext, cy, cx, out_h, out_w, acc_dtype):
@@ -104,6 +104,6 @@ def stencil2d_pallas(x: jax.Array, cy: tuple[float, ...],
         body, grid=(b, nby, nbx), in_specs=views,
         out_specs=pl.BlockSpec((1, block_y, block_x), lambda i, jy, jx: (i, jy, jx)),
         out_shape=jax.ShapeDtypeStruct((b, ny, nx), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret)(*([x] * 9))

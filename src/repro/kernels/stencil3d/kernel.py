@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _body(c, zm, zp, ym, yp, xm, xp, o, *, cz, cy, cx, bz, by, bx,
@@ -99,7 +99,7 @@ def stencil3d_pallas(x: jax.Array, cz: tuple[float, ...],
         out_specs=pl.BlockSpec((1, bz, by, bx),
                                lambda i, jz, jy, jx: (i, jz, jy, jx)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret)(*([x] * 7))

@@ -35,8 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo import collective_bytes, remat_duplication
 from repro.configs import SHAPES, ArchConfig, ShapeSpec, cells, get_config
 from repro.core.roofline import TpuRooflineTerms
-from repro.distributed.sharding import (INFERENCE_RULES, mesh_context,
-                                        resolve_spec)
+from repro.distributed.sharding import INFERENCE_RULES, resolve_spec
 from repro.launch.mesh import make_production_mesh
 from repro.models import params as pr
 from repro.models.registry import build_model, input_specs
@@ -135,7 +134,7 @@ def _lower_and_compile(cfg: ArchConfig, shape: ShapeSpec, mesh, chips,
     Lowering runs inside ``jax.sharding.set_mesh(mesh)`` so the models'
     activation sharding constraints (distributed.sharding.constrain) resolve
     against the production mesh."""
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         return _lower_and_compile_inner(cfg, shape, mesh, chips, remat,
                                         force_unroll, infer_layout)
 

@@ -29,7 +29,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, get_reduced_config
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
-from repro.distributed.sharding import mesh_context, resolve_spec
+from repro.distributed.sharding import resolve_spec
 from repro.launch.mesh import make_local_mesh
 from repro.models import params as pr
 from repro.models.registry import build_model, input_arrays
@@ -103,7 +103,7 @@ def main(argv=None) -> int:
         print(f"[resume] from checkpoint step {step} "
               f"(train step {start_step})", flush=True)
 
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         step_fn = jax.jit(make_train_step(
             model, cfg, opt_cfg, remat=args.remat,
             microbatches=args.microbatches))

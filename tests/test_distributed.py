@@ -26,10 +26,10 @@ from repro.distributed.halo import (distributed_stencil1d,
                                     distributed_stencil2d,
                                     distributed_stencil3d)
 from repro.distributed.collectives import int8_psum
-from repro.distributed.sharding import make_mesh_compat, shard_map_compat
+from jax.sharding import AxisType
 
 out = {}
-mesh = make_mesh_compat((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 
 spec = StencilSpec((512,), (3,), (tuple((rng.normal(size=7)/7).tolist()),),
@@ -59,9 +59,9 @@ x3 = rng.normal(size=(16, 32, 48)).astype(np.float32)
 out["d3"] = bool(np.allclose(np.asarray(f3(jnp.asarray(x3))),
                              stencil_reference_np(x3, spec3), atol=1e-5))
 
-mesh1 = make_mesh_compat((8,), ("d",))
+mesh1 = jax.make_mesh((8,), ("d",), axis_types=(AxisType.Auto,))
 xq = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
-g = jax.jit(shard_map_compat(lambda v: int8_psum(v, "d"), mesh=mesh1,
+g = jax.jit(jax.shard_map(lambda v: int8_psum(v, "d"), mesh=mesh1,
                           in_specs=P("d"), out_specs=P("d")))
 y = g(xq)
 true = jnp.sum(xq, axis=0)

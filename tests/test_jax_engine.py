@@ -219,6 +219,32 @@ def test_explore_batched_stage1_matches_sequential():
     b = {str(key(p)): (p.cycles, p.pes) for p in bat.ideal_points}
     assert s == b and s
     assert seq.best().objectives() == bat.best().objectives()
+    assert bat.stats["n_host_fallback"] == 0
+
+
+def test_explore_batched_counts_host_fallback(monkeypatch):
+    """A lane the jax lowering rejects is re-run on the host engine and
+    counted, so a sweep that left the device shows it in its stats."""
+    from repro.core.engine import jax_engine
+    from repro.explore import Budget, SpaceOptions, explore
+    real = jax_engine.run_compiled_batch
+    rejected = []
+
+    def reject_first_lane(items, max_cycles):
+        out = real(items, max_cycles=max_cycles)
+        out[0] = jax_engine.JaxLoweringError("rejected by the test")
+        rejected.append(1)
+        return out
+
+    monkeypatch.setattr(jax_engine, "run_compiled_batch", reject_first_lane)
+    spec = heat_2d(18, 24, dtype="float64")
+    opts = SpaceOptions(fabrics=())
+    bat = explore(spec, CGRA, options=opts, budget=Budget(batch_size=8))
+    seq = explore(spec, CGRA, options=opts, budget=Budget())
+    assert rejected and bat.stats["n_host_fallback"] == len(rejected)
+    assert seq.stats["n_host_fallback"] == 0
+    assert ({p.config: p.cycles for p in bat.ideal_points}
+            == {p.config: p.cycles for p in seq.ideal_points})
 
 
 def test_explore_batched_respects_max_evals():

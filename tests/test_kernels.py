@@ -7,7 +7,8 @@ import pytest
 
 from repro.kernels.conv1d.ops import causal_conv1d
 from repro.kernels.conv1d.ref import conv1d_ref
-from repro.kernels.stencil1d.ops import plan_1d_blocks, stencil1d
+from repro.kernels.stencil1d.ops import (VMEM_BUDGET_BYTES, plan_1d_blocks,
+                                         stencil1d)
 from repro.kernels.stencil1d.ref import stencil1d_ref
 from repro.kernels.stencil2d.ops import stencil2d
 from repro.kernels.stencil2d.ref import stencil2d_ref
@@ -48,8 +49,11 @@ def test_stencil1d_sweep(rng, b, n, r, t, variant, dtype):
 def test_stencil1d_block_planner():
     bb, bn = plan_1d_blocks(n=194400, batch=1, radius=8, timesteps=4)
     assert bn % 128 == 0 and bn >= 8 * 4
-    ws = bb * (3 * bn + 2 * (bn + 2 * 32)) * 4
-    assert ws <= 8 * 1024 * 1024
+    # the mxu variant's (bn+2h) x (bn+2h-2r) f32 band must fit the budget
+    _, bn_mxu = plan_1d_blocks(n=194400, batch=1, radius=8, timesteps=4,
+                               variant="mxu")
+    w = bn_mxu + 2 * 32
+    assert bn_mxu < bn and w * (w - 16) * 4 <= VMEM_BUDGET_BYTES
 
 
 # --------------------------------------------------------------------------
