@@ -65,6 +65,7 @@ from repro.core.engine.compile import (CompiledPlan, StaleCompiledPlanError,
 from repro.telemetry.probe import (ST_INACTIVE, ST_INPUT_STARVED, ST_MEM_ARB,
                                    ST_OUTPUT_BLOCKED, format_stall_summary,
                                    summary_from_state)
+from repro.telemetry.spans import span
 
 __all__ = ["SEMANTICS", "JaxLoweringError", "run", "run_compiled_batch"]
 
@@ -544,9 +545,21 @@ def _finalize(cp: CompiledPlan, flat_in, flat_out, lane: dict,
 _GROUP = 8
 
 
+def _device_tables(lows: list[tuple[int, LoweredPlan, float]]) -> dict:
+    """The lanes' lowered tables stacked along a leading lane axis, with
+    their memory credits, copied to the device."""
+    stacked = {k: np.stack([lp.tables[k] for _, lp, _ in lows])
+               for k in lows[0][1].tables}
+    stacked["epc"] = np.asarray([epc for _, _, epc in lows],
+                                dtype=np.float64)
+    stacked["cap4"] = 4.0 * stacked["epc"]
+    return {k: jnp.asarray(v) for k, v in stacked.items()}
+
+
 def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
                                          float]],
-                       max_cycles: int = 50_000_000
+                       max_cycles: int = 50_000_000,
+                       loop_counts: dict | None = None
                        ) -> list[RawStats | SimDeadlock | JaxLoweringError]:
     """Simulate B compiled plans in one batched device call per lane group
     (``_GROUP`` cycle-similar lanes each).
@@ -556,7 +569,12 @@ def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
     (with ``flat_out`` filled and per-node ``fires``/``max_occupancy``
     written back), a ``SimDeadlock`` *value* (not raised) for lanes that
     deadlock or time out, or a ``JaxLoweringError`` value for lanes the
-    lowering rejects — one bad lane never poisons its siblings."""
+    lowering rejects — one bad lane never poisons its siblings.
+
+    ``loop_counts``, if given, gains the lockstep loop's work, summed over
+    the groups: ``device_iterations``, each group's ``while_loop``
+    iterations (its slowest lane's cycles), and ``lane_iterations``, the
+    group's lanes times its iterations."""
     max_cycles = min(int(max_cycles), (1 << 31) - 2)   # int32 cycle counter
     results: list = [None] * len(items)
     good: list[tuple[int, CompiledPlan, float]] = []
@@ -583,30 +601,35 @@ def run_compiled_batch(items: list[tuple[CompiledPlan, np.ndarray, np.ndarray,
     with jax.enable_x64(True):
         for g0 in range(0, len(good), _GROUP):
             grp = good[g0:g0 + _GROUP]
-            dims = shared_dims([cp for _, cp, _ in grp])
-            lows: list[tuple[int, LoweredPlan, float]] = []
-            for i, cp, epc in grp:
-                try:
-                    lows.append((i, lower(cp, dims), epc))
-                except JaxLoweringError as e:
-                    results[i] = e
+            with span("repro.jax_engine.lower"):
+                dims = shared_dims([cp for _, cp, _ in grp])
+                lows: list[tuple[int, LoweredPlan, float]] = []
+                for i, cp, epc in grp:
+                    try:
+                        lows.append((i, lower(cp, dims), epc))
+                    except JaxLoweringError as e:
+                        results[i] = e
+                tables = _device_tables(lows) if lows else None
             if not lows:
                 continue
-            stacked = {k: np.stack([lp.tables[k] for _, lp, _ in lows])
-                       for k in lows[0][1].tables}
-            stacked["epc"] = np.asarray([epc for _, _, epc in lows],
-                                        dtype=np.float64)
-            stacked["cap4"] = 4.0 * stacked["epc"]
-            out = _sweep({k: jnp.asarray(v) for k, v in stacked.items()},
-                         jnp.int32(max_cycles))
-            qlen, active, fires, maxocc, _credit, cycles, status = \
-                [np.asarray(a) for a in out]
-            for j, (i, lp, _epc) in enumerate(lows):
-                lane = {"qlen": qlen[j], "active": active[j],
-                        "fires": fires[j], "maxocc": maxocc[j],
-                        "cycles": cycles[j], "status": status[j]}
-                results[i] = _finalize(lp.cp, items[i][1], items[i][2],
-                                       lane, max_cycles)
+            with span("repro.jax_engine.loop"):
+                out = _sweep(tables, jnp.int32(max_cycles))
+                qlen, active, fires, maxocc, _credit, cycles, status = \
+                    [np.asarray(a) for a in out]
+            if loop_counts is not None:
+                iterations = int(cycles.max())
+                loop_counts["device_iterations"] = (
+                    loop_counts.get("device_iterations", 0) + iterations)
+                loop_counts["lane_iterations"] = (
+                    loop_counts.get("lane_iterations", 0)
+                    + iterations * len(lows))
+            with span("repro.jax_engine.value_pass"):
+                for j, (i, lp, _epc) in enumerate(lows):
+                    lane = {"qlen": qlen[j], "active": active[j],
+                            "fires": fires[j], "maxocc": maxocc[j],
+                            "cycles": cycles[j], "status": status[j]}
+                    results[i] = _finalize(lp.cp, items[i][1], items[i][2],
+                                           lane, max_cycles)
     return results
 
 

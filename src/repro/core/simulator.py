@@ -47,6 +47,7 @@ from repro.core.engine import vector as _vector
 from repro.core.engine.common import SimDeadlock, mem_elems_per_cycle
 from repro.core.mapping import MappingPlan
 from repro.core.roofline import Machine, analyze
+from repro.telemetry.spans import span
 
 if TYPE_CHECKING:  # pragma: no cover - avoids core <-> fabric import cycle
     from repro.fabric.route import RoutedFabric
@@ -181,7 +182,7 @@ def _to_result(plan, machine: Machine, stats, flat_out, out_shape,
 def simulate_batch(items, machine: Machine,
                    max_cycles: int = 50_000_000,
                    mem_efficiency: float = 1.0,
-                   engine: str = "jax"):
+                   engine: str = "jax", loop_counts: dict | None = None):
     """Simulate B independent ``(plan, x)`` pairs and return a list of
     per-lane outcomes, aligned with ``items``: a :class:`SimResult` on
     success, or the failure **as a value** — ``SimDeadlock`` for
@@ -194,7 +195,8 @@ def simulate_batch(items, machine: Machine,
     (:mod:`repro.core.engine.jax_engine`); this is the auto-tuner's batched
     stage-1 evaluator.  Any other engine falls back to a sequential loop
     with the same returns-as-values contract (handy for benchmarking the
-    batched path against the sequential one)."""
+    batched path against the sequential one).  ``loop_counts``: see
+    ``jax_engine.run_compiled_batch``."""
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}; choose one of {ENGINES}")
     prepped = []
@@ -210,15 +212,16 @@ def simulate_batch(items, machine: Machine,
         from repro.core.engine import jax_engine as _jax   # lazy: pulls jax
         from repro.core.engine.compile import compiled_for
         batch, out = [], [None] * len(prepped)
-        for i, (plan, flat_in, flat_out, _os, epc) in enumerate(prepped):
-            try:
-                batch.append((i, compiled_for(plan, None), flat_in,
-                              flat_out, epc))
-            except ValueError as e:        # uncompilable op vocabulary
-                out[i] = _jax.JaxLoweringError(str(e))
+        with span("repro.jax_engine.lower"):
+            for i, (plan, flat_in, flat_out, _os, epc) in enumerate(prepped):
+                try:
+                    batch.append((i, compiled_for(plan, None), flat_in,
+                                  flat_out, epc))
+                except ValueError as e:        # uncompilable op vocabulary
+                    out[i] = _jax.JaxLoweringError(str(e))
         raw = _jax.run_compiled_batch(
             [(cp, fi, fo, epc) for _i, cp, fi, fo, epc in batch],
-            max_cycles=max_cycles)
+            max_cycles=max_cycles, loop_counts=loop_counts)
         for (i, _cp, _fi, _fo, _epc), stats in zip(batch, raw):
             plan, _flat_in, flat_out, out_shape, _e = prepped[i]
             if isinstance(stats, SimDeadlock):

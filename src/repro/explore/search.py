@@ -38,6 +38,7 @@ from repro.explore.prune import (PruneLog, fits_fabric, prune_space,
                                  static_prune_reason)
 from repro.explore.space import (MappingConfig, SpaceOptions, as_target,
                                  enumerate_space)
+from repro.telemetry.spans import span as profiler_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +120,8 @@ class _BudgetState:
         self.evals = 0
         self.sim_cycles = 0
         self.host_fallbacks = 0           # batched lanes rerouted to the host
+        # the batched device loop's work (jax_engine.run_compiled_batch)
+        self.loop_counts = {"device_iterations": 0, "lane_iterations": 0}
 
     def exhausted(self) -> bool:
         b = self.budget
@@ -385,40 +388,41 @@ def _stage1_batched(target, kept, machine, *, base_scope: dict,
         chunk = pending[i:i + take]
         i += len(chunk)
         lanes = []                        # (cfg, key, plan, x, t0)
-        for cfg, key in chunk:
-            t0 = time.perf_counter()
-            try:
-                plan = target.build(cfg)
-            except ValueError as e:
-                failures.append({"config": cfg.canonical(),
-                                 "reason": f"build: {e}", "cached": False})
-                cache.put(key, {"failed": f"build: {e}"})
-                span(key, f"failed: build: {e}", t0)
-                continue
-            if static_gate:
-                sr = static_prune_reason(plan)
-                if sr is not None:
-                    reason, suggested = sr
-                    if paranoia:
-                        _paranoia_check(target, cfg, plan, machine, state,
-                                        None)
-                    rec = {"config": cfg.canonical(), "reason": reason,
-                           "cached": False}
-                    ent = {"failed": reason}
-                    hint = _hint_json(suggested)
-                    if hint:
-                        rec["suggested_capacities"] = hint
-                        ent["suggested_capacities"] = hint
-                    failures.append(rec)
-                    cache.put(key, ent)
-                    span(key, f"failed: {reason}", t0)
+        with profiler_span("repro.explore.build"):
+            for cfg, key in chunk:
+                t0 = time.perf_counter()
+                try:
+                    plan = target.build(cfg)
+                except ValueError as e:
+                    failures.append({"config": cfg.canonical(),
+                                     "reason": f"build: {e}", "cached": False})
+                    cache.put(key, {"failed": f"build: {e}"})
+                    span(key, f"failed: build: {e}", t0)
                     continue
-            lanes.append((cfg, key, plan, target.make_input(plan), t0))
+                if static_gate:
+                    sr = static_prune_reason(plan)
+                    if sr is not None:
+                        reason, suggested = sr
+                        if paranoia:
+                            _paranoia_check(target, cfg, plan, machine, state,
+                                            None)
+                        rec = {"config": cfg.canonical(), "reason": reason,
+                               "cached": False}
+                        ent = {"failed": reason}
+                        hint = _hint_json(suggested)
+                        if hint:
+                            rec["suggested_capacities"] = hint
+                            ent["suggested_capacities"] = hint
+                        failures.append(rec)
+                        cache.put(key, ent)
+                        span(key, f"failed: {reason}", t0)
+                        continue
+                lanes.append((cfg, key, plan, target.make_input(plan), t0))
         if not lanes:
             continue
         raw = simulate_batch([(p, x) for _c, _k, p, x, _t in lanes],
                              machine, max_cycles=state.budget.sim_max_cycles,
-                             engine="jax")
+                             engine="jax", loop_counts=state.loop_counts)
         for (cfg, key, plan, x, t0), res in zip(lanes, raw):
             if isinstance(res, NotImplementedError):
                 # lowering rejected this lane: sequential fallback, measured
@@ -491,6 +495,15 @@ def explore(target, machine: Machine, *,
     cache entry — and never reach an engine.  ``static_paranoia``
     additionally simulates every statically-rejected config and asserts it
     really deadlocks (the fuzz-suite soundness gate; expensive)."""
+    with profiler_span("repro.explore"):
+        return _explore(target, machine, options, budget, cache, engine,
+                        workload_timesteps, verify, telemetry, static_verify,
+                        static_paranoia)
+
+
+def _explore(target, machine, options, budget, cache, engine,
+             workload_timesteps, verify, telemetry, static_verify,
+             static_paranoia) -> ExploreResult:
     t0 = time.perf_counter()
     target = as_target(target, workload_timesteps=workload_timesteps)
     options = options or SpaceOptions()
@@ -498,9 +511,10 @@ def explore(target, machine: Machine, *,
     if not isinstance(cache, EvalCache):
         cache = EvalCache(cache)
 
-    configs, analytic_cfg = enumerate_space(target, machine, options)
-    kept, plog = prune_space(target, machine, configs, options,
-                             keep=analytic_cfg)
+    with profiler_span("repro.explore.space"):
+        configs, analytic_cfg = enumerate_space(target, machine, options)
+        kept, plog = prune_space(target, machine, configs, options,
+                                 keep=analytic_cfg)
     if telemetry is not None:       # pruned configs get a (zero-cost) span
         for cfg, reason in plog.dropped:
             telemetry.span(f"pruned {reason}", cat="tuner",
@@ -606,6 +620,7 @@ def explore(target, machine: Machine, *,
         "static_pruned": sum(1 for f in failures
                              if f["reason"].startswith("static-")),
         "sim_cycles_total": state.sim_cycles,
+        **state.loop_counts,
         "wall_s": round(time.perf_counter() - t0, 3),
         "cache": cache.stats(),
     }

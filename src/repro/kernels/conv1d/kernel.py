@@ -60,4 +60,4 @@ def conv1d_pallas(x: jax.Array, w: jax.Array, *, block_s: int = 256,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret)(x, x, w)
+        interpret=interpret, name="conv1d")(x, x, w)

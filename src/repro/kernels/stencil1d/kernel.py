@@ -147,7 +147,7 @@ def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
         return pl.pallas_call(
             body, grid=(nb, nn), in_specs=views, out_specs=out_spec,
             out_shape=out_shape, compiler_params=params,
-            interpret=interpret)(x, x, x)
+            interpret=interpret, name="stencil1d")(x, x, x)
     elif variant == "mxu":
         band = jnp.asarray(make_band(coeffs, block_n + 2 * halo,
                                      block_n + 2 * halo - 2 * r))
@@ -158,5 +158,5 @@ def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
         return pl.pallas_call(
             body, grid=(nb, nn), in_specs=views + [band_spec],
             out_specs=out_spec, out_shape=out_shape, compiler_params=params,
-            interpret=interpret)(x, x, x, band)
+            interpret=interpret, name="stencil1d")(x, x, x, band)
     raise ValueError(f"unknown variant {variant!r}")

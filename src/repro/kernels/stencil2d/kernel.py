@@ -106,4 +106,4 @@ def stencil2d_pallas(x: jax.Array, cy: tuple[float, ...],
         out_shape=jax.ShapeDtypeStruct((b, ny, nx), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret)(*([x] * 9))
+        interpret=interpret, name="stencil2d")(*([x] * 9))

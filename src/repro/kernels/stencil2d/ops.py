@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.core.spec import StencilSpec
 from repro.kernels.stencil2d.kernel import stencil2d_pallas
 from repro.kernels.stencil2d.ref import stencil2d_ref
+from repro.telemetry.spans import span
 
 # three quarters of the 16 MiB of VMEM the TPU v5e compiler gives a kernel
 # by default: the rest is headroom for what the working-set model misses
@@ -62,19 +63,23 @@ def stencil2d(x: jax.Array, cy: tuple[float, ...], cx: tuple[float, ...], *,
     if backend == "xla":
         return stencil2d_ref(x, cy, cx, timesteps=timesteps)
 
-    interpret = jax.default_backend() != "tpu"
-    ry, rx = (len(cy) - 1) // 2, (len(cx) - 1) // 2
-    lead = x.shape[:-2]
-    ny, nx = x.shape[-2:]
-    xb = x.reshape((-1, ny, nx))
-    if block is None:
-        block = plan_2d_blocks(ny, nx, ry, rx, timesteps)
-    by, bx = block
-    py = _next_multiple(ny, by) - ny
-    px = _next_multiple(nx, bx) - nx
-    xp = jnp.pad(xb, ((0, 0), (0, py), (0, px)))
-    out = _dispatch(xp, cy, cx, timesteps, by, bx, interpret, ny, nx)
-    return out[:, :ny, :nx].reshape(*lead, ny, nx)
+    with span("repro.stencil2d"):
+        interpret = jax.default_backend() != "tpu"
+        ry, rx = (len(cy) - 1) // 2, (len(cx) - 1) // 2
+        lead = x.shape[:-2]
+        ny, nx = x.shape[-2:]
+        if block is None:
+            block = plan_2d_blocks(ny, nx, ry, rx, timesteps)
+        by, bx = block
+        py = _next_multiple(ny, by) - ny
+        px = _next_multiple(nx, bx) - nx
+        with span("repro.stencil2d.pad"):
+            xp = jnp.pad(x.reshape((-1, ny, nx)),
+                         ((0, 0), (0, py), (0, px)))
+        with span("repro.stencil2d.call"):
+            out = _dispatch(xp, cy, cx, timesteps, by, bx, interpret, ny, nx)
+        with span("repro.stencil2d.slice"):
+            return out[:, :ny, :nx].reshape(*lead, ny, nx)
 
 
 @functools.partial(jax.jit,

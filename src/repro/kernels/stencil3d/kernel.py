@@ -102,4 +102,4 @@ def stencil3d_pallas(x: jax.Array, cz: tuple[float, ...],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
-        interpret=interpret)(*([x] * 7))
+        interpret=interpret, name="stencil3d")(*([x] * 7))

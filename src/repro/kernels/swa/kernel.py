@@ -111,4 +111,4 @@ def swa_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret)(q, k, v)
+        interpret=interpret, name="swa")(q, k, v)

@@ -12,6 +12,10 @@ stats, parity-gated across both engines) and free when absent (``telemetry=
 None`` keeps the engines on their uninstrumented hot paths).  The mapping
 auto-tuner records a search span per evaluation into the same sink
 (``explore(..., telemetry=tel)``), so one trace file can hold a whole sweep.
+
+``span(name)`` records the program's host passes (kernel entry, tuner,
+batched engine) on the ``jax.profiler``'s clock instead, beside the
+device's ops; ``SPANS`` lists them.
 """
 from repro.telemetry.attribution import (CycleAccounting, attribute,
                                          render_attribution, stage_label)
@@ -24,6 +28,7 @@ from repro.telemetry.probe import (ST_FIRED, ST_INACTIVE, ST_INPUT_STARVED,
                                    format_stall_summary)
 from repro.telemetry.report import (bottleneck_table, render_report,
                                     utilization_grid)
+from repro.telemetry.spans import SPANS, span
 from repro.telemetry.trace import trace_events, validate_trace, write_trace
 
 __all__ = ["Telemetry", "STALL_CAUSES", "STATE_NAMES", "ST_INACTIVE",
@@ -32,4 +37,5 @@ __all__ = ["Telemetry", "STALL_CAUSES", "STATE_NAMES", "ST_INACTIVE",
            "write_trace", "validate_trace", "utilization_grid",
            "bottleneck_table", "render_report", "CycleAccounting",
            "attribute", "render_attribution", "stage_label",
-           "case_records", "append_history", "load_history"]
+           "case_records", "append_history", "load_history", "SPANS",
+           "span"]

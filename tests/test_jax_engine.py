@@ -230,8 +230,8 @@ def test_explore_batched_counts_host_fallback(monkeypatch):
     real = jax_engine.run_compiled_batch
     rejected = []
 
-    def reject_first_lane(items, max_cycles):
-        out = real(items, max_cycles=max_cycles)
+    def reject_first_lane(items, max_cycles, **kw):
+        out = real(items, max_cycles=max_cycles, **kw)
         out[0] = jax_engine.JaxLoweringError("rejected by the test")
         rejected.append(1)
         return out
@@ -245,6 +245,26 @@ def test_explore_batched_counts_host_fallback(monkeypatch):
     assert seq.stats["n_host_fallback"] == 0
     assert ({p.config: p.cycles for p in bat.ideal_points}
             == {p.config: p.cycles for p in seq.ideal_points})
+
+
+@pytest.mark.parametrize("batch_size", [None, 1, 8])
+def test_explore_counts_device_loop_iterations(batch_size):
+    """``device_iterations`` sums each lane group's ``while_loop``
+    iterations (its slowest lane's cycles) and ``lane_iterations`` the
+    group's lanes times those.  One lane a call puts every lane in a group
+    of its own; eight put these five lanes in one group; the sequential
+    stage 1 runs no device loop."""
+    from repro.explore import Budget, SpaceOptions, explore
+    spec = heat_2d(18, 24, dtype="float64")
+    res = explore(spec, CGRA, options=SpaceOptions(fabrics=()),
+                  budget=Budget(batch_size=batch_size))
+    cycles = [p.sim_cycles for p in res.ideal_points]
+    assert res.stats["n_failures"] == 0 and len(cycles) == 5
+    want = {None: (0, 0), 1: (sum(cycles), sum(cycles)),
+            8: (max(cycles), len(cycles) * max(cycles))}[batch_size]
+    assert (res.stats["device_iterations"],
+            res.stats["lane_iterations"]) == want
+    assert res.stats["sim_cycles_total"] == sum(cycles)
 
 
 def test_explore_batched_respects_max_evals():

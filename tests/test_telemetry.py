@@ -663,3 +663,18 @@ def test_state_names_cover_constants():
     assert STATE_NAMES[ST_MEM_ARB] == "memory_arbitration"
     assert STATE_NAMES[ST_NET_WAIT] == "network_contention"
     assert STALL_CAUSES == STATE_NAMES[2:]
+
+
+def test_every_profiler_span_is_listed_and_recorded():
+    """``SPANS`` lists exactly the spans the program's code records."""
+    import re
+    from pathlib import Path
+
+    from repro.telemetry import SPANS
+    src = Path(__file__).resolve().parents[1] / "src" / "repro"
+    used = set()
+    for path in src.rglob("*.py"):
+        used |= set(re.findall(r'span\("(repro\.[\w.]+)"\)',
+                               path.read_text()))
+    assert used == set(SPANS)
+    assert all(name.startswith("repro.") and SPANS[name] for name in SPANS)

@@ -6,6 +6,8 @@ planner picks.  The TPU compiler refuses here what it would refuse on the
 chip (a tile that overflows VMEM, an unaligned slice), so these guard the
 planners at no chip time.  Nothing runs: a pass says nothing about results.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -71,6 +73,19 @@ def test_stencil2d_planner_choice_compiles(one_chip, on_tpu, spec, timesteps):
         lambda x: stencil2d(x, *spec.coeffs, timesteps=timesteps),
         spec.grid_shape, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stencil2d_kernel_keeps_its_name(one_chip, on_tpu):
+    """The kernel's custom call is named for its ``pallas_call``'s
+    ``name``, not for the Python function that builds it."""
+    from repro.kernels.stencil2d.ops import stencil2d
+    spec = paper_stencil_2d(dtype="float32")
+    text = _compile(lambda x: stencil2d(x, *spec.coeffs), spec.grid_shape,
+                    one_chip).as_text()
+    calls = re.findall(r"^\s*%?([\w.-]+) = .* custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert calls and all(re.fullmatch(r"stencil2d(\.\d+)?", c)
+                         for c in calls), calls
 
 
 def test_stencil3d_512_cube_compiles(one_chip, on_tpu):
