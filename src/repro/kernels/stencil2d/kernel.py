@@ -74,13 +74,18 @@ def _body(tl, tc, tr, ml, mc, mr, bl, bc, br, o, *, cy, cx, timesteps,
 @functools.partial(
     jax.jit,
     static_argnames=("cy", "cx", "timesteps", "block_y", "block_x",
-                     "interpret"))
+                     "extent", "interpret"))
 def stencil2d_pallas(x: jax.Array, cy: tuple[float, ...],
                      cx: tuple[float, ...], *, timesteps: int = 1,
                      block_y: int = 128, block_x: int = 256,
+                     extent: tuple[int, int],
                      interpret: bool = False) -> jax.Array:
     """x: (B, ny, nx) -> (B, ny, nx). ny % block_y == 0, nx % block_x == 0,
-    ry*T <= block_y, rx*T <= block_x (ops.py pads)."""
+    ry*T <= block_y, rx*T <= block_x (ops.py pads).
+
+    ``extent`` is the grid's true (ny, nx): x holds it at its origin and
+    zeros past it.  Inputs outside it read as zero and outputs outside its
+    interior are written as zero, so this is the only boundary mask."""
     b, ny, nx = x.shape
     ry = (len(cy) - 1) // 2
     rx = (len(cx) - 1) // 2
@@ -99,7 +104,7 @@ def stencil2d_pallas(x: jax.Array, cy: tuple[float, ...],
     views = [vspec(dy, dx) for dy in (-1, 0, 1) for dx in (-1, 0, 1)]
     body = functools.partial(
         _body, cy=cy, cx=cx, timesteps=timesteps, block_y=block_y,
-        block_x=block_x, ny=ny, nx=nx, out_dtype=x.dtype)
+        block_x=block_x, ny=extent[0], nx=extent[1], out_dtype=x.dtype)
     return pl.pallas_call(
         body, grid=(b, nby, nbx), in_specs=views,
         out_specs=pl.BlockSpec((1, block_y, block_x), lambda i, jy, jx: (i, jy, jx)),

@@ -56,44 +56,40 @@ def stencil2d(x: jax.Array, cy: tuple[float, ...], cx: tuple[float, ...], *,
               timesteps: int = 1, backend: str = "auto",
               block: tuple[int, int] | None = None) -> jax.Array:
     """Batched 2D star stencil over the last two axes (y=-2, x=-1)."""
-    cy = tuple(float(c) for c in cy)
-    cx = tuple(float(c) for c in cx)
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if backend == "xla":
-        return stencil2d_ref(x, cy, cx, timesteps=timesteps)
+        return stencil2d_ref(x, tuple(float(c) for c in cy),
+                             tuple(float(c) for c in cx), timesteps=timesteps)
 
     with span("repro.stencil2d"):
-        interpret = jax.default_backend() != "tpu"
-        ry, rx = (len(cy) - 1) // 2, (len(cx) - 1) // 2
-        lead = x.shape[:-2]
-        ny, nx = x.shape[-2:]
-        if block is None:
-            block = plan_2d_blocks(ny, nx, ry, rx, timesteps)
-        by, bx = block
-        py = _next_multiple(ny, by) - ny
-        px = _next_multiple(nx, bx) - nx
         with span("repro.stencil2d.pad"):
-            xp = jnp.pad(x.reshape((-1, ny, nx)),
-                         ((0, 0), (0, py), (0, px)))
+            cy = tuple(float(c) for c in cy)
+            cx = tuple(float(c) for c in cx)
+            interpret = jax.default_backend() != "tpu"
         with span("repro.stencil2d.call"):
-            out = _dispatch(xp, cy, cx, timesteps, by, bx, interpret, ny, nx)
+            out = _entry(x, cy, cx, timesteps, block, interpret)
         with span("repro.stencil2d.slice"):
-            return out[:, :ny, :nx].reshape(*lead, ny, nx)
+            return out
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cy", "cx", "timesteps", "by", "bx",
-                                    "interpret", "tny", "tnx"))
-def _dispatch(xp, cy, cx, timesteps, by, bx, interpret, tny, tnx):
-    y = stencil2d_pallas(xp, cy, cx, timesteps=timesteps, block_y=by,
-                         block_x=bx, interpret=interpret)
+                   static_argnames=("cy", "cx", "timesteps", "block",
+                                    "interpret"))
+def _entry(x, cy, cx, timesteps, block, interpret):
+    """The whole entry as one program: where no padding is needed the
+    reshapes are bitcasts and the pad and slice vanish, so the kernel is
+    the only pass over the grid."""
     ry, rx = (len(cy) - 1) // 2, (len(cx) - 1) // 2
-    hy, hx = ry * timesteps, rx * timesteps
-    jj = jnp.arange(xp.shape[-2])[:, None]
-    ii = jnp.arange(xp.shape[-1])[None, :]
-    valid = (jj >= hy) & (jj < tny - hy) & (ii >= hx) & (ii < tnx - hx)
-    return jnp.where(valid, y, 0).astype(y.dtype)
+    lead = x.shape[:-2]
+    ny, nx = x.shape[-2:]
+    by, bx = block or plan_2d_blocks(ny, nx, ry, rx, timesteps)
+    py = _next_multiple(ny, by) - ny
+    px = _next_multiple(nx, bx) - nx
+    xp = jnp.pad(x.reshape((-1, ny, nx)), ((0, 0), (0, py), (0, px)))
+    out = stencil2d_pallas(xp, cy, cx, timesteps=timesteps, block_y=by,
+                           block_x=bx, extent=(ny, nx), interpret=interpret)
+    return out[:, :ny, :nx].reshape(*lead, ny, nx)
 
 
 def stencil2d_from_spec(x: jax.Array, spec: StencilSpec, **kw) -> jax.Array:

@@ -18,11 +18,15 @@ SPANS = {
     "repro.stencil2d":
         "kernels/stencil2d/ops.py stencil2d, Pallas branch: the whole entry",
     "repro.stencil2d.pad":
-        "the input's reshape to (b, ny, nx) and jnp.pad",
+        "the host's preparation of the jitted _entry's static arguments: "
+        "the taps as floats and the choice of interpret mode (the block "
+        "plan, reshape and pad run inside _entry)",
     "repro.stencil2d.call":
-        "the call into the jitted _dispatch, until its enqueue returns",
+        "the call into the jitted _entry (reshape, pad, kernel, slice, "
+        "reshape back: one program), until its enqueue returns",
     "repro.stencil2d.slice":
-        "out[:, :ny, :nx] and the reshape back",
+        "the hand-back of _entry's result to the caller (the slice runs "
+        "inside _entry)",
     "repro.explore":
         "explore/search.py explore: the whole search",
     "repro.explore.space":

@@ -64,6 +64,7 @@ def test_stencil1d_block_planner():
     (1, 72, 160, 2, 2, 3, "float32"),
     (2, 40, 140, 3, 1, 1, "float32"),
     (1, 64, 128, 1, 1, 2, "bfloat16"),
+    (2, 45, 200, 2, 2, 2, "float32"),       # padded on both axes
 ])
 def test_stencil2d_sweep(rng, b, ny, nx, ry, rx, t, dtype):
     cy = tuple((rng.normal(size=2 * ry + 1) / (2 * ry + 1)).tolist())
@@ -75,6 +76,18 @@ def test_stencil2d_sweep(rng, b, ny, nx, ry, rx, t, dtype):
     yr = stencil2d_ref(x, cy, tuple(cx), timesteps=t)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32), atol=TOL[dtype])
+
+
+@pytest.mark.parametrize("shape", [(45, 200), (2, 45, 200), (2, 3, 64, 128)])
+def test_stencil2d_entry_is_one_dispatch(shape):
+    """Reshape, pad, kernel and slice are one jitted program: a call
+    enqueues it once and runs nothing eagerly around it."""
+    x = jnp.zeros(shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: stencil2d(
+        x, (0.1, 0.8, 0.1), (0.2, 0.0, 0.2), timesteps=2, backend="pallas",
+        block=(8, 128)))(x)
+    assert [e.primitive.name for e in jaxpr.eqns] == ["jit"]
+    assert jaxpr.out_avals[0].shape == shape
 
 
 # --------------------------------------------------------------------------

@@ -75,6 +75,33 @@ def test_stencil2d_planner_choice_compiles(one_chip, on_tpu, spec, timesteps):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("spec,padded", [
+    (heat_2d(16384, 16384), False),
+    (paper_stencil_2d(dtype="float32"), True)], ids=["heat16384", "seismic"])
+def test_stencil2d_entry_is_one_program(one_chip, on_tpu, spec, padded):
+    """The entry compiles to one program holding one kernel and no copy.
+    Where the grid needs no padding the reshapes are bitcasts and nothing
+    but the kernel passes over the grid: no relayout copy, no second
+    boundary mask.  A padded grid keeps its pad and slice, which are
+    small beside the kernel."""
+    from repro.kernels.stencil2d.ops import stencil2d
+    text = _compile(lambda x: stencil2d(x, *spec.coeffs), spec.grid_shape,
+                    one_chip).as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", entry, re.M)
+    assert entry.count('custom_call_target="tpu_custom_call"') == 1
+    assert "copy" not in {op for _, op in ops}, ops
+    if not padded:
+        size = int(np.prod(spec.grid_shape))
+        grid_passes = [op for dims, op in ops
+                       if int(np.prod([int(d) for d in dims.split(",") if d]))
+                       == size
+                       and op not in ("parameter", "bitcast", "custom-call")]
+        assert grid_passes == [], ops
+
+
 def test_stencil2d_kernel_keeps_its_name(one_chip, on_tpu):
     """The kernel's custom call is named for its ``pallas_call``'s
     ``name``, not for the Python function that builds it."""
