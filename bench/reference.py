@@ -21,27 +21,33 @@ CONTROL_DTYPE = {"float64": np.float32, "float32": ml_dtypes.bfloat16}
 
 
 def _sweep_rows(x: np.ndarray, coeffs, radii, lo: int, hi: int,
-                dtype) -> np.ndarray:
-    """Rows [lo, hi) of one unmasked sweep of ``x`` along axis 0 onwards."""
-    n0 = x.shape[0]
-    out = np.zeros((hi - lo,) + x.shape[1:], dtype)
+                dtype, axis: int = 0) -> np.ndarray:
+    """Planes [lo, hi) along ``axis`` of one unmasked sweep of ``x``, zeros
+    read outside ``x``."""
+    n0 = x.shape[axis]
+    shape = list(x.shape)
+    shape[axis] = hi - lo
+    out = np.zeros(shape, dtype)
     for ax, (r, cs) in enumerate(zip(radii, coeffs)):
         for k, c in enumerate(cs):
             if c == 0.0:
                 continue
             c = dtype(c)
             off = k - r
-            if ax == 0:
+            dst = [slice(None)] * x.ndim
+            src = [slice(None)] * x.ndim
+            if ax == axis:
                 a, b = max(lo + off, 0), min(hi + off, n0)
                 if a >= b:
                     continue
-                src = x[a:b].astype(dtype, copy=False)
-                out[a - off - lo:b - off - lo] += c * src
+                src[ax], dst[ax] = slice(a, b), slice(a - off - lo,
+                                                      b - off - lo)
+                out[tuple(dst)] += c * x[tuple(src)].astype(dtype, copy=False)
                 continue
-            blk = x[lo:hi].astype(dtype, copy=False)
+            src[axis] = slice(lo, hi)
+            blk = x[tuple(src)].astype(dtype, copy=False)
             n = x.shape[ax]
-            dst = [slice(None)] * x.ndim
-            src = [slice(None)] * x.ndim
+            src[axis] = slice(None)
             if off > 0:
                 src[ax], dst[ax] = slice(off, n), slice(0, n - off)
             else:
@@ -50,19 +56,27 @@ def _sweep_rows(x: np.ndarray, coeffs, radii, lo: int, hi: int,
     return out
 
 
-def _rim_mask(shape, radii, depth: int, lo: int, hi: int) -> np.ndarray:
-    """True where rows [lo, hi) of ``shape`` lie ``r * depth`` or more from
-    every face."""
-    mask = np.ones((hi - lo,) + tuple(shape[1:]), bool)
+def _rim_mask(shape, radii, depth: int, lo: int, hi: int,
+              axis: int = 0) -> np.ndarray:
+    """True where planes [lo, hi) along ``axis`` of ``shape`` lie
+    ``r * depth`` or more from every face."""
+    size = list(shape)
+    size[axis] = hi - lo
+    mask = np.ones(size, bool)
     for ax, r in enumerate(radii):
         h = r * depth
         if h == 0:
             continue
-        idx = np.arange(lo, hi) if ax == 0 else np.arange(shape[ax])
+        idx = np.arange(lo, hi) if ax == axis else np.arange(shape[ax])
         ok = (idx >= h) & (idx < shape[ax] - h)
         mask &= np.expand_dims(ok, tuple(i for i in range(len(shape))
                                          if i != ax))
     return mask
+
+
+def default_threads() -> int:
+    """Threads for a reference computed in blocks: one a core, at most 16."""
+    return min(16, len(os.sched_getaffinity(0)))
 
 
 def star_stencil(x: np.ndarray, coeffs, timesteps: int = 1, *,
@@ -78,7 +92,7 @@ def star_stencil(x: np.ndarray, coeffs, timesteps: int = 1, *,
     threads), so a whole grid can be compared without a second copy."""
     radii = tuple((len(c) - 1) // 2 for c in coeffs)
     if threads is None:
-        threads = min(16, len(os.sched_getaffinity(0)))
+        threads = default_threads()
     cur = np.asarray(x)
     n0 = cur.shape[0]
     if rows_per_block is None:
@@ -104,6 +118,30 @@ def star_stencil(x: np.ndarray, coeffs, timesteps: int = 1, *,
             list(pool.map(run, blocks))
         cur = nxt
     return cur
+
+
+def star_stencil_plane(slab: np.ndarray, coeffs, timesteps: int, *,
+                       axis: int, lo: int, plane: int, grid,
+                       dtype=np.float64) -> np.ndarray:
+    """Plane ``plane`` along ``axis`` of ``timesteps`` masked sweeps of the
+    star stencil over a whole grid of shape ``grid``, from ``slab``: the
+    grid's input planes [lo, lo + slab.shape[axis]) along ``axis``, every
+    other axis whole.  The slab must hold every input plane within
+    ``r * timesteps`` of ``plane`` that lies in the grid.  The zero rim is
+    the grid's, not the slab's, so a slab inside the grid is interior.
+    Each output is summed as ``star_stencil`` sums it, to the same bits."""
+    radii = tuple((len(c) - 1) // 2 for c in coeffs)
+    cur, cur_lo = slab, lo
+    for t in range(timesteps):
+        reach = radii[axis] * (timesteps - 1 - t)
+        a = max(plane - reach, cur_lo)
+        b = min(plane + reach + 1, cur_lo + cur.shape[axis])
+        acc = _sweep_rows(cur, coeffs, radii, a - cur_lo, b - cur_lo, dtype,
+                          axis)
+        cur = np.where(_rim_mask(grid, radii, t + 1, a, b, axis), acc,
+                       dtype(0))
+        cur_lo = a
+    return np.take(cur, plane - cur_lo, axis=axis)
 
 
 def rounding_bound(coeffs, timesteps: int, x_absmax: float,

@@ -44,14 +44,11 @@ TINY = {"heat2d_an5d": [64, 256], "seismic2d_paper": [56, 160]}
 
 
 def heat3d_config(grid, layout=None):
-    """A 3D heat configuration in the benchmark's format (no cell of the
-    benchmark runs one yet): on a 2x2 mesh, or on one chip."""
-    from repro.core.spec import heat_3d
-    spec = heat_3d(*grid)
-    return {"name": "heat3d", "grid": list(grid), "radii": [1, 1, 1],
-            "coeffs": [list(c) for c in spec.coeffs], "dtype": "float32",
-            "chips": 4 if layout else 1, "layout": layout,
-            "limits": {"sweeps": {"err_over_bound": 100.0}}}
+    """The committed 3D heat configuration (``heat3d_x4``) at another grid:
+    on a 2x2 mesh, or on one chip."""
+    config = json.loads((BENCH / "configs" / "heat3d_x4.json").read_text())
+    return {**config, "name": "heat3d", "grid": list(grid),
+            "chips": 4 if layout else 1, "layout": layout}
 
 
 MESH = {"mesh": [2, 2], "axes": ["pod", "data"],
@@ -109,7 +106,8 @@ def test_every_metric_has_its_reader():
 
 @pytest.mark.parametrize("name,build", [
     ("heat2d_an5d", "heat_2d(16384, 16384)"),
-    ("seismic2d_paper", "paper_stencil_2d(449, 960, r=12)")])
+    ("seismic2d_paper", "paper_stencil_2d(449, 960, r=12)"),
+    ("heat3d_x4", "heat_3d(1408, 1408, 1408, alpha=0.1)")])
 def test_config_states_the_programs_stencil(name, build):
     """The taps a configuration states are the ones the repo's own
     constructor builds for that deployment."""
@@ -223,7 +221,7 @@ def test_one_chip_3d_sweeps_at_a_tiny_size(pallas_interpret):
 
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "answer_altered",
-                                   "control"])
+                                   "answer_nan", "control"])
 @pytest.mark.parametrize("cell", ONE_CHIP_SWEEPS)
 def test_sweeps_faults_are_not_correct(cell, fault, pallas_interpret,
                                        monkeypatch):
@@ -238,6 +236,8 @@ def test_sweeps_faults_are_not_correct(cell, fault, pallas_interpret,
             return (lambda x: x + 0), sharding
         if fault == "answer_altered":
             return (lambda x: fn(x).at[..., 7, 9].add(1e-3)), sharding
+        if fault == "answer_nan":
+            return (lambda x: fn(x).at[..., 7, 9].set(float("nan"))), sharding
         return fn, sharding
 
     monkeypatch.setattr(d, "_entry", faulty_entry)
@@ -284,6 +284,113 @@ def test_four_chip_cell_on_virtual_devices_and_without_exchange():
     assert not res["no_exchange"]["correct"]
 
 
+_MESH_FAULTS = r"""
+import os, json
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import loops, run
+bench, w, _config, traffic = run.load_cell("heat3d_x4.sweeps")
+config = %r
+res = {}
+args = dict(seed=2**32 + 9, seconds=0.2, trace=False,
+            devices=jax.devices()[:4], peak=run.peak_for("TPU v5 lite"))
+res["sound"] = run.run_cell(bench, w, config, traffic, **args)
+for fault in ("state_unchanged", "answer_altered", "answer_nan", "control"):
+    d = loops.Sweeps(config, traffic, 5, jax.devices()[:4])
+    entry = d._entry
+
+    def faulty_entry(fault=fault, entry=entry):
+        fn, sharding = entry()
+        if fault == "state_unchanged":
+            return (lambda x: x + 0), sharding
+        b = config["grid"][0] // 2         # a plane beside the z boundary
+        if fault == "answer_altered":
+            return (lambda x: fn(x).at[b, 9, 9].add(1e-3)), sharding
+        if fault == "answer_nan":
+            return (lambda x: fn(x).at[b, 9, 9].set(float("nan"))), sharding
+        return fn, sharding
+
+    d._entry = faulty_entry
+    d.setup()
+    d.window(0.05)
+    out = d.outputs()
+    if fault == "control":
+        out = d.control(out)
+    checks, failed = d.compare(out)
+    res[fault] = {"correct": all(c.ok for c in checks) and failed == 0,
+                  "checks": {c.name: c.value for c in checks}}
+    if fault == "state_unchanged":
+        res["shapes"] = [list(a.shape) for g in out["planes"]
+                         for a in (g["x"], g["y"])]
+        res["planes"] = [g["planes"] for g in out["planes"]]
+print(json.dumps(res))
+"""
+
+#: a grid with more interior z planes than a mesh cell samples
+MESH_GRID = (160, 16, 128)
+
+
+@pytest.fixture(scope="module")
+def mesh_faults():
+    """The mesh cell's configuration at ``MESH_GRID`` on four virtual
+    devices: a sound run, and each planted fault, in one process."""
+    code = _MESH_FAULTS % (heat3d_config(MESH_GRID, MESH),)
+    p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=_env(),
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_cell_is_correct_at_a_tiny_size(mesh_faults):
+    res = mesh_faults["sound"]
+    assert res["correct"], res["checks"]
+    assert res["device"]["count"] == 4
+    assert set(res["metrics"]) == {"gpts_per_s.x4", "setup_s"}
+
+
+@pytest.mark.parametrize("fault", ["state_unchanged", "answer_altered",
+                                   "answer_nan", "control"])
+def test_sweeps_faults_are_not_correct_on_a_mesh(mesh_faults, fault):
+    assert not mesh_faults[fault]["correct"], mesh_faults[fault]
+
+
+def test_mesh_outputs_hold_the_compared_planes_only(mesh_faults):
+    """What a mesh cell brings to the host is the compared planes and
+    their slabs, never an array of the whole grid's shape."""
+    planes = mesh_faults["planes"]
+    assert len(planes[0]) == 6 + loops.Sweeps.SAMPLED_PLANES
+    for shape in mesh_faults["shapes"]:
+        assert shape != list(MESH_GRID)
+    assert mesh_faults["shapes"][0] == [3 * len(planes[0]), 16, 128]
+    assert mesh_faults["shapes"][5] == [160, 16, len(planes[2])]
+
+
+def test_compared_planes_at_the_cells_size():
+    """Face and shard-boundary planes at every seed, 64 interior z planes
+    that change with the seed."""
+    config = run.load_cell("heat3d_x4.sweeps")[2]
+    grid, radii, layout = config["grid"], config["radii"], config["layout"]
+    fixed = [{0, 1, 703, 704, 1406, 1407}, {0, 1, 703, 704, 1406, 1407},
+             {0, 1, 1406, 1407}]
+    seen = []
+    for seed in (0, 7, 2 ** 31 + 3, 2 ** 33 + 1):
+        planes = loops.compared_planes(grid, radii, 1, layout, seed, 64)
+        assert [set(p) & f for p, f in zip(planes, fixed)] == fixed
+        assert planes[1:] == [sorted(f) for f in fixed[1:]]
+        assert len(planes[0]) == 70 and planes[0] == sorted(set(planes[0]))
+        assert planes == loops.compared_planes(grid, radii, 1, layout, seed,
+                                               64)
+        seen.append(frozenset(planes[0]) - fixed[0])
+    assert len(set(seen)) == len(seen)
+    # the slabs and planes brought to the host: under a quarter of the grid
+    brought = sum(len(p) * 4 * math.prod(grid) // n
+                  for p, n in zip(planes, grid))
+    assert brought < math.prod(grid) // 4
+    # two sweeps a call widen the face and boundary planes by r each
+    planes = loops.compared_planes(grid, radii, 2, layout, 0, 0)
+    assert planes[0] == [0, 1, 2, 702, 703, 704, 705, 1405, 1406, 1407]
+
+
 @pytest.fixture(scope="module")
 def tiny_tune():
     """The tuner cell at a 32x64 grid with its count table recorded by the
@@ -311,7 +418,7 @@ def test_tune_cell_is_correct_at_a_tiny_size(tiny_tune):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_the_lanes",
                                    "cycles_altered", "answer_altered",
-                                   "control"])
+                                   "answer_nan", "control"])
 def test_tune_faults_are_not_correct(tiny_tune, fault):
     d = _tune_loop(tiny_tune)
     d.setup()
@@ -329,6 +436,8 @@ def test_tune_faults_are_not_correct(tiny_tune, fault):
             c["lanes"][0]["cycles"] += 1
         elif fault == "answer_altered":
             c["lanes"][-1]["output"][20, 30] += 1e-9
+        elif fault == "answer_nan":
+            c["lanes"][-1]["output"][20, 30] = np.nan
     if fault == "control":
         out = d.control(out)
     checks, failed = d.compare(out)
@@ -426,6 +535,30 @@ def test_reference_matches_a_direct_sum():
                     + coeffs[1][a] * x[i, j + a - 1, k]
                     + coeffs[2][a] * x[i, j, k + a - 1] for a in range(3))
     np.testing.assert_allclose(got, want, rtol=1e-13, atol=1e-15)
+
+
+@pytest.mark.parametrize("timesteps", [1, 2])
+@pytest.mark.parametrize("axis", [0, 1, 2])
+def test_plane_reference_matches_the_whole_grid(axis, timesteps):
+    """A plane from its slab, at the faces and inside, is the same bits as
+    that plane of the whole grid's reference."""
+    rng = np.random.default_rng(axis)
+    grid = (12, 10, 14)
+    x = rng.normal(size=grid).astype(np.float32)
+    coeffs = [[0.05, 0.2, 0.3, 0.1, 0.02], [0.05, 0.0, 0.15],
+              [0.1, 0.0, 0.1]]
+    radii = [2, 1, 1]
+    whole = reference.star_stencil(x, coeffs, timesteps)
+    h = radii[axis] * timesteps
+    n = grid[axis]
+    for plane in (0, 1, h, n // 2, n - h - 1, n - 1):
+        lo = max(plane - h, 0)
+        hi = min(plane + h + 1, n)
+        slab = np.take(x, np.arange(lo, hi), axis=axis)
+        got = reference.star_stencil_plane(slab, coeffs, timesteps,
+                                           axis=axis, lo=lo, plane=plane,
+                                           grid=grid)
+        np.testing.assert_array_equal(got, np.take(whole, plane, axis=axis))
 
 
 def test_pareto_front():
