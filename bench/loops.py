@@ -57,12 +57,12 @@ class Check:
 class Sweeps:
     """Back-to-back stencil sweeps of one seeded, device-resident grid.
 
-    One chip: the public kernel entry (``stencil2d`` / ``stencil3d``, the
-    planner's block).  A mesh layout: the halo-exchanged step
-    (``distributed_stencil{2,3}d``) over the configured mesh.  The loop
-    keeps ``QUEUE_DEPTH`` calls queued behind the running one and waits on
-    the oldest, so the device never runs dry and outputs never pile up in
-    its memory.  Every call computes the same ``timesteps`` fused sweeps
+    One chip: the public kernel entry of the grid's rank (``stencil1d`` /
+    ``stencil2d`` / ``stencil3d``, the planner's block).  A mesh layout:
+    the halo-exchanged step (``distributed_stencil{2,3}d``) over the
+    configured mesh.  The loop keeps ``QUEUE_DEPTH`` calls queued behind
+    the running one and waits on the oldest, so the device never runs dry
+    and outputs never pile up in its memory.  Every call computes the same ``timesteps`` fused sweeps
     (the traffic's) of the same grid, so the last call's output stands for
     all of them in the comparison: on one chip the whole grid, on a mesh
     the planes ``compared_planes`` chooses, since a whole grid too large
@@ -105,16 +105,25 @@ class Sweeps:
             return step, NamedSharding(mesh, PartitionSpec(
                 *layout["partition"]))
         from jax.sharding import SingleDeviceSharding
-        if ndim == 2:
+        if ndim == 1:
+            from repro.kernels.stencil1d.ops import stencil1d
+
+            def step(x):
+                return stencil1d(x, coeffs[0], timesteps=t)
+        elif ndim == 2:
             from repro.kernels.stencil2d.ops import stencil2d
 
             def step(x):
                 return stencil2d(x, *coeffs, timesteps=t)
-        else:
+        elif ndim == 3:
             from repro.kernels.stencil3d.ops import stencil3d
 
             def step(x):
                 return stencil3d(x, *coeffs, timesteps=t)
+        else:
+            raise ValueError(f"a one-chip sweep runs the kernel entry of its "
+                             f"grid's rank, 1, 2 or 3; the grid "
+                             f"{cfg['grid']} has rank {ndim}")
         return step, SingleDeviceSharding(self.devices[0])
 
     def setup(self) -> None:

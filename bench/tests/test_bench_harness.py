@@ -40,7 +40,7 @@ SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
 PEAK = run.peak_for("TPU v5 lite")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-TINY = {"heat2d_an5d": [64, 256], "seismic2d_paper": [56, 160]}
+CONFIGS = {c["name"]: c["file"] for c in SPEC["configs"]}
 
 
 def heat3d_config(grid, layout=None):
@@ -104,19 +104,21 @@ def test_every_metric_has_its_reader():
     assert run.metric_path("loop_s.tune").name == "loop_s.tune.py"
 
 
-@pytest.mark.parametrize("name,build", [
-    ("heat2d_an5d", "heat_2d(16384, 16384)"),
-    ("seismic2d_paper", "paper_stencil_2d(449, 960, r=12)"),
-    ("heat3d_x4", "heat_3d(1408, 1408, 1408, alpha=0.1)")])
-def test_config_states_the_programs_stencil(name, build):
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_config_states_the_programs_stencil(name):
     """The taps a configuration states are the ones the repo's own
-    constructor builds for that deployment."""
-    from repro.core import spec as specs
-    config = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    built = eval(build, vars(specs))
+    constructor, named in the file, builds for that deployment; its tiny
+    grid for the CPU tests has the grid's rank."""
+    config = json.loads((ROOT / CONFIGS[name]).read_text())
+    assert config["name"] == name
+    call = config["constructor"]
+    module = call.split("(", 1)[0].rsplit(".", 1)[0]
+    built = eval(call[len(module) + 1:],
+                 vars(importlib.import_module(module)))
     assert tuple(config["grid"]) == built.grid_shape
     assert tuple(config["radii"]) == built.radii
     assert tuple(tuple(c) for c in config["coeffs"]) == built.coeffs
+    assert len(config["tiny_grid"]) == len(config["grid"])
 
 
 def test_peaks_are_keyed_by_device_kind():
@@ -129,7 +131,9 @@ def test_peaks_are_keyed_by_device_kind():
 @pytest.mark.parametrize("name,points,flops", [
     ("heat2d_an5d", 16384 * 16384, 9 * 16382 * 16382),
     ("seismic2d_paper", 449 * 960, 97 * 425 * 936),
-    ("heat3d", 1408 ** 3, 13 * 1406 ** 3)])
+    ("heat3d", 1408 ** 3, 13 * 1406 ** 3),
+    ("paper1d", 194400, 33 * 194384),
+    ("heat3d_an5d", 512 ** 3, 13 * 510 ** 3)])
 def test_work_of_one_sweep_by_hand(name, points, flops):
     if name == "heat3d":
         config = heat3d_config((1408, 1408, 1408))
@@ -171,16 +175,16 @@ def test_run_fails_with_only_the_benchmark_files(tmp_path):
 
 
 def _tiny(cell):
+    """The cell with its configuration at the file's own ``tiny_grid``."""
     bench, w, config, traffic = run.load_cell(cell)
-    config = json.loads(json.dumps(config))
-    config["grid"] = TINY[config["name"]]
+    config = {**config, "grid": list(config["tiny_grid"])}
     return bench, w, config, traffic
 
 
 @pytest.fixture
 def pallas_interpret(monkeypatch):
     """The kernel entries run their Pallas kernels in interpret mode."""
-    for dim in (2, 3):
+    for dim in (1, 2, 3):
         mod = importlib.import_module(f"repro.kernels.stencil{dim}d.ops")
         fn = getattr(mod, f"stencil{dim}d")
         monkeypatch.setattr(mod, f"stencil{dim}d",
@@ -214,10 +218,43 @@ def test_one_chip_3d_sweeps_at_a_tiny_size(pallas_interpret):
     """A 3D configuration without a mesh drives the 3D kernel entry, so a
     later one-chip 3D cell needs only its files."""
     bench, w, _config, traffic = run.load_cell("heat2d_an5d.sweeps")
-    config = heat3d_config((16, 16, 256))
+    config = heat3d_config(run.load_cell("heat3d_x4.sweeps")[2]["tiny_grid"])
     res = _run((bench, {**w, "name": "heat3d.sweeps"}, config, traffic))
     assert res["correct"], res["checks"]
     assert res["device"]["count"] == 1
+
+
+def test_one_chip_1d_sweeps_drive_the_1d_entry(pallas_interpret,
+                                               monkeypatch):
+    """A 1D configuration without a mesh drives ``stencil1d`` with its one
+    axis of taps, and reads correct."""
+    mod = importlib.import_module("repro.kernels.stencil1d.ops")
+    entry, calls = mod.stencil1d, []
+
+    def counted(x, coeffs, **kw):
+        calls.append((x.shape, tuple(coeffs), kw))
+        return entry(x, coeffs, **kw)
+
+    monkeypatch.setattr(mod, "stencil1d", counted)
+    cell = next(c for c in ONE_CHIP_SWEEPS
+                if len(run.load_cell(c)[2]["grid"]) == 1)
+    bench, w, config, traffic = _tiny(cell)
+    res = _run((bench, w, config, traffic))
+    assert res["correct"], res["checks"]
+    assert len(calls) == res["attempted"] + 1 > 1      # warm-up, window
+    assert calls[0] == ((config["grid"][0],), tuple(config["coeffs"][0]),
+                        {"timesteps": 1})
+
+
+def test_sweeps_refuse_a_grid_of_rank_four():
+    import jax
+    _bench, _w, config, traffic = _tiny("heat2d_an5d.sweeps")
+    d = loops.Sweeps({**config, "grid": [4, 4, 8, 128],
+                      "coeffs": config["coeffs"] * 2,
+                      "radii": config["radii"] * 2},
+                     traffic, 5, jax.devices()[:1])
+    with pytest.raises(ValueError, match="rank 4"):
+        d.setup()
 
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "answer_altered",
@@ -229,15 +266,16 @@ def test_sweeps_faults_are_not_correct(cell, fault, pallas_interpret,
     import jax
     d = loops.Sweeps(config, traffic, 5, jax.devices()[:1])
     entry = d._entry
+    at = (..., *(7, 9)[-len(config["grid"]):])       # a point of any rank
 
     def faulty_entry():
         fn, sharding = entry()
         if fault == "state_unchanged":
             return (lambda x: x + 0), sharding
         if fault == "answer_altered":
-            return (lambda x: fn(x).at[..., 7, 9].add(1e-3)), sharding
+            return (lambda x: fn(x).at[at].add(1e-3)), sharding
         if fault == "answer_nan":
-            return (lambda x: fn(x).at[..., 7, 9].set(float("nan"))), sharding
+            return (lambda x: fn(x).at[at].set(float("nan"))), sharding
         return fn, sharding
 
     monkeypatch.setattr(d, "_entry", faulty_entry)
@@ -587,6 +625,26 @@ def test_union_idle_and_exposed_collectives():
     assert run.metric_reader("idle_share.tune")(ctx) == pytest.approx(20.0)
 
 
+@pytest.mark.parametrize("kernel", ["stencil1d", "stencil3d"])
+def test_kernel_roofline_reads_its_own_kernel(kernel):
+    """``<kernel>_roofline``: a sweep's least time over the device time a
+    sweep of the kernel ops of that name; other kernels and the wrapper's
+    ops do not count, and with none of its kernel it reads nothing."""
+    ops = [_op(f"{kernel}.1", 0, 40, "kernel"), _op("fusion.2", 40, 50),
+           _op(f"{kernel}.1", 100, 140, "kernel"),
+           _op("stencil2d.1", 140, 190, "kernel"),
+           _op(f"{kernel}x.4", 190, 200, "kernel")]
+    tr = trace_reduce.Trace({"/device:TPU:0": ops},
+                            [trace_reduce.Op("bench.window", 0, 200)])
+    ctx = {"trace": tr, "window": (0, 200), "calls": 2, "chips": 1,
+           "work": {"points": 1, "bytes": 8190, "flops": 1}, "peak": PEAK}
+    read = run.metric_reader(f"{kernel}_roofline")
+    assert read(ctx) == pytest.approx(25.0)      # 10 ns over 40 ns a sweep
+    assert read({**ctx, "calls": 0}) is None
+    tr.devices["/device:TPU:0"] = ops[1:2] + ops[3:]
+    assert read(ctx) is None
+
+
 def test_op_kinds_from_hlo_text():
     assert trace_reduce.op_kind(
         '%custom-call.1 = f32[1,64,256]{2,1,0} custom-call(f32[1,64,256] '
@@ -640,6 +698,8 @@ def test_recorded_chip_trace_reduces(path):
     idle = run.metric_reader("idle_share.sweep")(ctx)
     assert 0 <= idle < 100
     assert run.metric_reader("halo_exposed_ms")(ctx) is None
+    assert run.metric_reader("stencil1d_roofline")(ctx) is None
+    assert run.metric_reader("stencil3d_roofline")(ctx) is None
     bd = tr.breakdown(lo, hi)
     assert 0 < len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
     assert math.isclose(sum(t for _n, t in bd["device_ops"]),

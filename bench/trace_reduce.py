@@ -105,20 +105,24 @@ class Trace:
         ops = [o for ops in self.devices.values() for o in ops]
         return min(o.start for o in ops), max(o.end for o in ops)
 
-    def ops(self, lo: int, hi: int, kind: str | None = None):
-        """Per device, its ops inside [lo, hi] (clipped), of ``kind``."""
+    def ops(self, lo: int, hi: int, kind: str | None = None,
+            name: str | None = None):
+        """Per device, its ops inside [lo, hi] (clipped), of ``kind``, and
+        named ``name`` where given: ``name`` or XLA's ``name.<n>``."""
         out = {}
         for dev, ops in self.devices.items():
             out[dev] = [o._replace(start=max(o.start, lo), end=min(o.end, hi))
                         for o in ops if o.end > lo and o.start < hi
-                        and (kind is None or o.kind == kind)]
+                        and (kind is None or o.kind == kind)
+                        and (name is None or o.name.split(".")[0] == name)]
         return out
 
-    def op_seconds(self, lo: int, hi: int, kind: str | None = None) -> float:
-        """Summed device time of the ops of ``kind``, averaged over
-        devices.  Ops nested in a loop op are summed with it; use
-        ``busy_s`` for the time the device was occupied."""
-        per = self.ops(lo, hi, kind)
+    def op_seconds(self, lo: int, hi: int, kind: str | None = None,
+                   name: str | None = None) -> float:
+        """Summed device time of the ops of ``kind`` (and ``name``),
+        averaged over devices.  Ops nested in a loop op are summed with it;
+        use ``busy_s`` for the time the device was occupied."""
+        per = self.ops(lo, hi, kind, name)
         if not per:
             return 0.0
         return sum(sum(o.end - o.start for o in ops)
