@@ -9,6 +9,9 @@ and every input element loaded from HBM feeds up to 2(rz+ry+rx)+1 taps.
 Fused T>1 needs corner halos (diamond composite support); ops.py runs T
 separate sweeps instead and documents the HBM-roundtrip trade (the §IV
 fusion analysis in core/temporal still applies to the CGRA/1D/2D paths).
+Each sweep masks its own boundary: reads past the grid's true extent are
+zero and outputs within the sweep's rim are written as zero, so no pass
+outside the kernel masks the grid.
 
 Grid: (batch, nbz, nby, nbx) with batch blocks of 1.
 """
@@ -23,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _body(c, zm, zp, ym, yp, xm, xp, o, *, cz, cy, cx, bz, by, bx,
-          nz, ny, nx, out_dtype):
+          nz, ny, nx, hz, hy, hx, out_dtype):
     jz, jy, jx = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     rz, ry, rx = ((len(cc) - 1) // 2 for cc in (cz, cy, cx))
     f32 = jnp.float32
@@ -63,18 +66,26 @@ def _body(c, zm, zp, ym, yp, xm, xp, o, *, cz, cy, cx, bz, by, bx,
     oz = gpos(jz, bz, nz, 0, (bz, 1, 1), 0)
     oy = gpos(jy, by, ny, 1, (1, by, 1), 0)
     ox = gpos(jx, bx, nx, 2, (1, 1, bx), 0)
-    valid = ((oz >= rz) & (oz < nz - rz) & (oy >= ry) & (oy < ny - ry) &
-             (ox >= rx) & (ox < nx - rx))
+    valid = ((oz >= hz) & (oz < nz - hz) & (oy >= hy) & (oy < ny - hy) &
+             (ox >= hx) & (ox < nx - hx))
     o[0] = jnp.where(valid, acc, 0).astype(out_dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cz", "cy", "cx", "block", "interpret"))
+    jax.jit, static_argnames=("cz", "cy", "cx", "block", "extent", "rim",
+                              "interpret"))
 def stencil3d_pallas(x: jax.Array, cz: tuple[float, ...],
                      cy: tuple[float, ...], cx: tuple[float, ...], *,
                      block: tuple[int, int, int] = (8, 16, 128),
+                     extent: tuple[int, int, int],
+                     rim: tuple[int, int, int],
                      interpret: bool = False) -> jax.Array:
-    """x: (B, nz, ny, nx) -> same shape; one star sweep."""
+    """x: (B, nz, ny, nx) -> same shape; one star sweep.
+
+    ``extent`` is the grid's true (nz, ny, nx): x holds it at its origin.
+    Inputs outside it read as zero, and outputs outside ``[rim, extent -
+    rim)`` on each axis are written as zero (sweep t of T has rim r·t), so
+    this is the only boundary mask."""
     b, nz, ny, nx = x.shape
     bz, by, bx = block
     assert nz % bz == 0 and ny % by == 0 and nx % bx == 0
@@ -93,7 +104,9 @@ def stencil3d_pallas(x: jax.Array, cz: tuple[float, ...],
              vspec(0, -1, 0), vspec(0, 1, 0), vspec(0, 0, -1),
              vspec(0, 0, 1)]
     body = functools.partial(_body, cz=cz, cy=cy, cx=cx, bz=bz, by=by, bx=bx,
-                             nz=nz, ny=ny, nx=nx, out_dtype=x.dtype)
+                             nz=extent[0], ny=extent[1], nx=extent[2],
+                             hz=rim[0], hy=rim[1], hx=rim[2],
+                             out_dtype=x.dtype)
     return pl.pallas_call(
         body, grid=(b, nbz, nby, nbx), in_specs=views,
         out_specs=pl.BlockSpec((1, bz, by, bx),

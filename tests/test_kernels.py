@@ -156,6 +156,10 @@ def test_kernels_grad_through_xla_path(rng):
     (2, 16, 32, 128, 2, 1, 3, 1, "float32"),
     (1, 24, 16, 128, 1, 2, 1, 2, "float32"),
     (1, 16, 16, 128, 1, 1, 1, 1, "bfloat16"),
+    (1, 20, 20, 200, 1, 1, 1, 1, "float32"),    # padded on every axis
+    (1, 20, 20, 200, 1, 1, 1, 2, "float32"),
+    (1, 20, 20, 200, 1, 2, 1, 3, "float32"),
+    (2, 20, 20, 200, 2, 1, 1, 2, "float32"),
 ])
 def test_stencil3d_sweep(rng, b, nz, ny, nx, rz, ry, rx, t, dtype):
     cz = tuple((rng.normal(size=2 * rz + 1) / (2 * rz + 1)).tolist())
@@ -169,3 +173,24 @@ def test_stencil3d_sweep(rng, b, nz, ny, nx, rz, ry, rx, t, dtype):
     yr = stencil3d_ref(x, cz, tuple(cy), tuple(cx), timesteps=t)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32), atol=TOL[dtype])
+
+
+@pytest.mark.parametrize("shape", [(20, 20, 200), (2, 20, 20, 200)])
+def test_stencil3d_entry_is_one_dispatch(rng, shape):
+    """Reshape, pad, the T kernel calls and slice are one jitted program: a
+    call enqueues it once and runs nothing eagerly around it, and inside an
+    outer jit it still gives the oracle's answer."""
+    cz, cy, cx = (0.1, 0.4, 0.1), (0.1, 0.0, 0.1), (0.1, 0.0, 0.1)
+
+    def fn(x):
+        return stencil3d(x, cz, cy, cx, timesteps=2, backend="pallas",
+                         block=(8, 16, 128))
+
+    x = _mk(rng, shape, "float32")
+    jaxpr = jax.make_jaxpr(fn)(x)
+    assert [e.primitive.name for e in jaxpr.eqns] == ["jit"]
+    assert jaxpr.out_avals[0].shape == shape
+    np.testing.assert_allclose(np.asarray(jax.jit(fn)(x)),
+                               np.asarray(stencil3d_ref(x, cz, cy, cx,
+                                                        timesteps=2)),
+                               atol=TOL["float32"])

@@ -123,6 +123,39 @@ def test_stencil3d_512_cube_compiles(one_chip, on_tpu):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("spec,timesteps,padded", [
+    (heat_3d(512, 512, 512), 1, False),
+    (heat_3d(512, 512, 512), 2, False),
+    (heat_3d(100, 40, 250), 1, True)], ids=["heat512", "heat512-T2", "padded"])
+def test_stencil3d_entry_is_one_program(one_chip, on_tpu, spec, timesteps,
+                                        padded):
+    """The entry compiles to one program holding one kernel a sweep and no
+    copy.  Where the block divides the grid the reshapes are bitcasts and
+    nothing but the kernels passes over the grid: no relayout copy, no
+    boundary mask of the entry's own.  A padded grid keeps its pad and
+    slice.  (The padded grid's y is a multiple of 8: the TPU stores a grid
+    whose y is not, such as (100, 60, 200), with z minor, and that array's
+    relayout to the kernel's row-major operand is a copy of its own.)"""
+    from repro.kernels.stencil3d.ops import stencil3d
+    text = _compile(
+        lambda x: stencil3d(x, *spec.coeffs, timesteps=timesteps),
+        spec.grid_shape, one_chip).as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", entry, re.M)
+    assert (entry.count('custom_call_target="tpu_custom_call"')
+            == timesteps)
+    assert "copy" not in {op for _, op in ops}, ops
+    if not padded:
+        size = int(np.prod(spec.grid_shape))
+        grid_passes = [op for dims, op in ops
+                       if int(np.prod([int(d) for d in dims.split(",") if d]))
+                       == size
+                       and op not in ("parameter", "bitcast", "custom-call")]
+        assert grid_passes == [], ops
+
+
 def test_jax_engine_sweep_compiles(one_chip):
     from repro.core.engine import jax_engine
     from repro.core.engine.compile import compiled_for
