@@ -170,7 +170,8 @@ def phase_simulator(n1: int = 194_400, seismic: tuple = (113, 240)) -> None:
 
 
 def phase_tuner(grid: tuple = (48, 96)) -> None:
-    """The heat2d stage-1 sweep of benchmarks/run.py's BENCH_pr9 case."""
+    """The heat2d stage-1 ideal sweep, batched on the device vs sequential
+    on the host."""
     from repro.core import CGRA
     from repro.core.spec import heat_2d
     from repro.explore import Budget, SpaceOptions, explore, tile_candidates

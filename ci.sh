@@ -1,75 +1,11 @@
 #!/usr/bin/env bash
 # Tier-1 verify gate (see ROADMAP.md) — one command for CI and local use.
 #
-# 1. pytest: the full suite (includes the interp-vs-vector engine
-#    cross-validation; the property sweep runs under hypothesis when
-#    installed and under the in-tree repro.testing.minihyp shim otherwise).
-# 1b. Static lint: every examples/ file with a lint_plans() hook runs
-#    through the static plan verifier (repro.analysis.lint --strict) —
-#    a committed example that deadlocks or fails a structural lint fails CI
-#    before any benchmark runs.
-# 2. Artifact refresh (smoke configuration): BENCH_pr2 single-op mappings,
-#    BENCH_pr3 program pipelines, BENCH_pr4 interp-vs-vector engine
-#    comparison (+ jax ideal-mode walls), BENCH_pr5 auto-tuner Pareto
-#    fronts, BENCH_pr9 batched-jax tuner-sweep throughput, BENCH_pr10
-#    static-verifier prune counts on capacity-stressed sweeps, plus a
-#    validated Perfetto trace for one routed case.  --engine all makes the
-#    refresh itself a drift gate (identical cycles/fires/outputs across
-#    interp/vector AND the ideal-mode jax engine — the jax parity gate);
-#    the pr5 refresh asserts non-dominated fronts and tuner-best <=
-#    analytical baseline; the pr9 refresh asserts identical per-config
-#    cycles and the >=3x batched-sweep throughput gate; the pr10 refresh
-#    asserts gated/ungated survivor parity and static_pruned ==
-#    engine-discovered deadlocks.
-# 3. Snapshot gate: the refreshed BENCH_pr4 vs the committed one —
-#    deterministic counters exact, walls within machine-noise tolerance.
-# 4. Trend gate: every refreshed artifact vs the last 5 records of
-#    BENCH_history.jsonl (benchmarks/bench_diff.py --trend).  The gate runs
-#    BEFORE the append on purpose: appending first would make every run
-#    its own baseline and the gate vacuous.
-# 5. Overhead gate: benchmarks/overhead_check.py re-times the routed smoke
-#    2d case with telemetry=None and fails if the wall creeps >2% above
-#    the rolling history median — the disabled-telemetry bound from
-#    docs/telemetry.md as an explicit failing check.
-# 6. History append + observatory report: the blessed measurements join
-#    BENCH_history.jsonl and the trend/attribution report renders.
+# The full pytest suite on the CPU: the engine cross-validation, the pinned
+# simulated counts and tuner fronts, the static lint of examples/, and the
+# harness tests of bench/.  It writes no tracked file.  The benchmark itself
+# is bench/run.py, declared in BENCHMARK.json, and runs on the chip.
 set -euo pipefail
 cd "$(dirname "$0")"
-# jax engine determinism pin: CPU backend only (no accidental device
-# pickup).  The 64-bit pin is scoped to the benchmark refresh below — the
-# seed model tests expect default-f32 promotion — and jax_engine enables
-# x64 in-process regardless, so the parity/throughput gates are f64 either
-# way; the env pin just makes the benchmark runs explicit about it.
 export JAX_PLATFORMS=cpu
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q "$@"
-
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m repro.analysis.lint examples/ --strict
-
-trace_out="${TRACE_OUT:-$(mktemp -d)/trace_2d.json}"
-prev_pr4="$(mktemp -d)/BENCH_pr4.prev.json"
-cp BENCH_pr4.json "$prev_pr4"
-
-JAX_ENABLE_X64=1 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.run --artifact BENCH_pr2.json \
-    --program-artifact BENCH_pr3.json --engine-artifact BENCH_pr4.json \
-    --explore BENCH_pr5.json --sweep-artifact BENCH_pr9.json \
-    --stress-artifact BENCH_pr10.json \
-    --trace "$trace_out" \
-    --engine all --smoke --artifact-only
-
-python benchmarks/bench_diff.py "$prev_pr4" BENCH_pr4.json \
-    --rtol 0.5 --atol 0.1
-
-for art in BENCH_pr2.json BENCH_pr3.json BENCH_pr4.json BENCH_pr5.json \
-    BENCH_pr9.json BENCH_pr10.json; do
-    python benchmarks/bench_diff.py "$art" --trend 5 \
-        --history BENCH_history.jsonl
-done
-
-python benchmarks/overhead_check.py --history BENCH_history.jsonl
-
-python benchmarks/observatory.py append BENCH_pr2.json BENCH_pr3.json \
-    BENCH_pr4.json BENCH_pr5.json BENCH_pr9.json BENCH_pr10.json \
-    --history BENCH_history.jsonl
-python benchmarks/observatory.py report --history BENCH_history.jsonl

@@ -9,7 +9,7 @@ deadlock, or ``max_cycles``).  Because the step is pure and fixed-shape it
 jits once per padded-shape bucket and — the actual point — ``vmap``s across
 a *batch* of independently-lowered plans, so the auto-tuner's stage-1 ideal
 sweep becomes one device call instead of B sequential ``vector.run`` calls
-(``repro.explore.search``, ``Budget.batch_size``; BENCH_pr9.json).
+(``repro.explore.search``, ``Budget.batch_size``).
 
 **Timing/value decoupling.**  The firing rule is value-independent: whether
 a node fires depends only on queue *lengths*, counters and the memory

@@ -2,8 +2,8 @@
 
 Every measured evaluation is stored under the canonical config hash
 (:meth:`repro.explore.space.MappingConfig.key` scoped by target + machine +
-mode), so re-running the same search — the ``ci.sh`` smoke refresh, an
-interrupted sweep, a second target sharing configs — pays only for configs
+mode), so re-running the same search — an interrupted sweep, a second
+target sharing configs — pays only for configs
 it has never simulated.  Failures (deadlocks, placement overflows) are
 cached too: a config known to deadlock is not re-simulated.
 

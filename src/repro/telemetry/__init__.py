@@ -19,8 +19,6 @@ device's ops; ``SPANS`` lists them.
 """
 from repro.telemetry.attribution import (CycleAccounting, attribute,
                                          render_attribution, stage_label)
-from repro.telemetry.metrics import (append_history, case_records,
-                                     load_history)
 from repro.telemetry.probe import (ST_FIRED, ST_INACTIVE, ST_INPUT_STARVED,
                                    ST_MEM_ARB, ST_NET_WAIT,
                                    ST_OUTPUT_BLOCKED, STALL_CAUSES,
@@ -36,6 +34,5 @@ __all__ = ["Telemetry", "STALL_CAUSES", "STATE_NAMES", "ST_INACTIVE",
            "ST_NET_WAIT", "format_stall_summary", "trace_events",
            "write_trace", "validate_trace", "utilization_grid",
            "bottleneck_table", "render_report", "CycleAccounting",
-           "attribute", "render_attribution", "stage_label",
-           "case_records", "append_history", "load_history", "SPANS",
+           "attribute", "render_attribution", "stage_label", "SPANS",
            "span"]

@@ -6,9 +6,8 @@ to ``repro.explore.explore(..., telemetry=)`` (the tuner records one span per
 evaluation).  The contract with the engines:
 
 * **zero cost when absent** — engines take ``telemetry=None`` and guard every
-  probe with one local ``is not None`` check; the disabled path must stay
-  within the BENCH_pr4 wall-clock envelope (ci.sh gates this with
-  ``benchmarks/bench_diff.py``).
+  probe with one local ``is not None`` check, so the disabled path is the
+  uninstrumented one.
 * **exact** — the telemetry counters are not estimates: summed, they equal
   the engine's own aggregate stats bit-for-bit (``totals()`` vs ``SimResult``
   / ``RawStats``; parity-gated in ``tests/test_telemetry.py``).

@@ -16,7 +16,7 @@ The mapping from probes to tracks:
 
 Events are emitted globally sorted by timestamp with integer ``ts``/``dur``
 — :func:`validate_trace` checks that (plus the required keys) and is run by
-the tests and by ``benchmarks/run.py --trace``.
+the tests.
 """
 from __future__ import annotations
 

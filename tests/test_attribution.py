@@ -10,9 +10,8 @@ The cycle-accounting profiler's two hard contracts:
   stage table, critical path, bottleneck label) must serialize identically
   for the interpreter and the compiled vector engine.
 
-Plus the metrics layer (fingerprinted history records), the observatory /
-overhead-check scripts, the tuner's bottleneck labels, and the routed
-auto-capacity regression gate (satellite 1).
+Plus the tuner's bottleneck labels and the routed auto-capacity regression
+gate (satellite 1).
 """
 import json
 
@@ -184,88 +183,6 @@ def test_render_attribution_smoke(rng):
     assert "cycle accounting" in text and "critical path" in text
     for stage in STAGE_ORDER:
         assert stage in text
-
-
-# ---------------------------------------------------------------------------
-# metrics layer: fingerprinted records, append-only history
-# ---------------------------------------------------------------------------
-def test_metrics_records_and_history(tmp_path):
-    from repro.telemetry.metrics import (append_history, case_records,
-                                         fingerprint, flatten_case,
-                                         history_for, load_history,
-                                         trend_values)
-    assert flatten_case({"a": 1, "b": {"c": 2.5, "d": {"e": "x"}}}) == \
-        {"a": 1, "b.c": 2.5, "b.d.e": "x"}
-    art = {"schema": "bench_pr4/v1", "config": "smoke",
-           "cases": {"2d": {"cycles_routed": 642, "vector_wall_s": 0.3,
-                            "grid": [30, 48], "workers": 8,
-                            "engines": ["interp", "vector"]}}}
-    recs = case_records(art, source="BENCH_pr4.json", ts=1000.0)
-    assert len(recs) == 1
-    r = recs[0]
-    assert r["counters"] == {"cycles_routed": 642, "workers": 8}
-    assert r["walls"] == {"vector_wall_s": 0.3}
-    assert r["meta"]["grid"] == [30, 48]
-    # fingerprint = identity, not score: same experiment, changed counters
-    art2 = json.loads(json.dumps(art))
-    art2["cases"]["2d"]["cycles_routed"] = 999
-    assert case_records(art2, ts=2000.0)[0]["fingerprint"] == \
-        r["fingerprint"]
-    assert fingerprint("s", "c", "x", {}) != fingerprint("s", "c", "y", {})
-
-    hist = str(tmp_path / "h.jsonl")
-    assert append_history(hist, recs) == 1
-    append_history(hist, case_records(art2, ts=2000.0))
-    with open(hist, "a") as f:                  # torn append survives
-        f.write('{"broken json\n\n')
-    loaded = load_history(hist)
-    assert len(loaded) == 2
-    line = history_for(loaded, "bench_pr4/v1", "smoke", "2d")
-    assert trend_values(line, "cycles_routed") == [642, 999]
-    assert trend_values(line, "cycles_routed", last=1) == [999]
-    assert trend_values(line, "vector_wall_s", kind="walls") == [0.3, 0.3]
-
-
-def test_observatory_append_and_report(tmp_path, capsys):
-    from benchmarks.observatory import main as obs
-    art = {"schema": "bench_pr4/v1", "config": "smoke",
-           "cases": {"2d": {"cycles_routed": 642, "vector_wall_s": 0.3,
-                            "bottleneck": "fill-bound",
-                            "stall_breakdown": {"input_starved": 10,
-                                                "network_contention": 30},
-                            "phases": {"fill": 438, "steady": 0,
-                                       "drain": 204}}}}
-    a = tmp_path / "BENCH_x.json"
-    a.write_text(json.dumps(art))
-    hist = str(tmp_path / "h.jsonl")
-    assert obs(["append", str(a), "--history", hist]) == 0
-    art["cases"]["2d"]["cycles_routed"] = 600
-    a.write_text(json.dumps(art))
-    assert obs(["append", str(a), "--history", hist]) == 0
-    assert obs(["report", "--history", hist]) == 0
-    out = capsys.readouterr().out
-    assert "cycles_routed: 600" in out
-    assert "bottleneck: fill-bound" in out
-    assert "network_contention" in out
-    # partial artifacts never enter the trajectory
-    art["errors"] = {"3d": "boom"}
-    a.write_text(json.dumps(art))
-    assert obs(["append", str(a), "--history", hist]) == 1
-
-
-def test_overhead_check_gates_against_history(tmp_path, monkeypatch):
-    import benchmarks.overhead_check as oc
-    hist = str(tmp_path / "h.jsonl")
-    monkeypatch.setattr(oc, "measure", lambda repeats: (0.40, 642))
-    assert oc.main(["--history", hist]) == 0    # seeds the trend
-    assert oc.main(["--history", hist]) == 0    # equal to median: pass
-    monkeypatch.setattr(oc, "measure", lambda repeats: (0.40 * 1.05, 642))
-    assert oc.main(["--history", hist, "--atol", "0"]) == 1  # >2% creep
-    monkeypatch.setattr(oc, "measure", lambda repeats: (0.40, 642))
-    from repro.telemetry.metrics import load_history
-    n_before = len(load_history(hist))
-    assert oc.main(["--history", hist, "--no-append"]) == 0
-    assert len(load_history(hist)) == n_before
 
 
 # ---------------------------------------------------------------------------

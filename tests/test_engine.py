@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core import CGRA, SimDeadlock, map_1d, map_2d, map_3d, simulate
-from repro.core.spec import (StencilSpec, heat_2d, heat_3d, paper_stencil_2d)
+from repro.core.spec import (StencilSpec, heat_2d, heat_3d, paper_stencil_1d,
+                             paper_stencil_2d)
 from repro.fabric import FabricTopology, place, route
 from repro.program import (CombineOp, StencilOp, StencilProgram,
                            hdiff_program, lower, two_stage_heat)
@@ -174,8 +175,7 @@ def test_max_cycles_identical(rng):
 def test_vector_faster_on_routed_program():
     """The point of the compiled engine: wall-clock on a routed program
     pipeline.  Deliberately loose (best-of-2, 1.2x) so a loaded CI host
-    cannot flake it — BENCH_pr4.json tracks the real speedup, >=5x on the
-    full-size pr3 cases."""
+    cannot flake it."""
     import time
     prog = two_stage_heat(24, 32)
     rng = np.random.default_rng(1)
@@ -192,6 +192,91 @@ def test_vector_faster_on_routed_program():
             best = min(best, time.perf_counter() - t0)
         walls[engine] = best
     assert walls["interp"] > 1.2 * walls["vector"], walls
+
+
+# ---------------------------------------------------------------------------
+# exact simulated counts: a change to the simulator's semantics that moves
+# every engine alike still fails here
+# ---------------------------------------------------------------------------
+def _routed_pair(mk_plan, x):
+    """Ideal and routed runs of fresh plans; the routed one placed on the
+    16x16 mesh with placement seed 0."""
+    ideal_plan, routed_plan = mk_plan(), mk_plan()
+    fab = route(place(routed_plan, FabricTopology.mesh(16, 16), seed=0))
+    ideal = simulate(ideal_plan, x, CGRA, engine="vector")
+    routed = simulate(routed_plan, x, CGRA, fabric=fab, engine="vector")
+    assert np.array_equal(ideal.output, routed.output)
+    return ideal, routed, fab, len(ideal_plan.dfg.nodes)
+
+
+def _counts(mk_plan, x):
+    ideal, routed, _, n_nodes = _routed_pair(mk_plan, x)
+    return (ideal.cycles, routed.cycles, n_nodes,
+            routed.fabric["stall_cycles"], routed.fabric["token_hops"])
+
+
+def _spec_counts(spec, mapper):
+    x = np.random.default_rng(0).normal(size=spec.grid_shape)
+    return _counts(lambda: mapper(spec, workers=8), x)
+
+
+def _program_counts(prog):
+    ins = {f: np.random.default_rng(0).normal(size=prog.grid_shape)
+           for f in prog.in_fields}
+    return _counts(lambda: lower(prog, workers=8),
+                   lower(prog, workers=8).pack_inputs(ins))
+
+
+def _fusion_counts(prog, w=4):
+    """The program fused into one DAG vs each op as its own sweep through
+    memory, ideal and routed cycles summed over the ops."""
+    rng = np.random.default_rng(0)
+    ins = {f: rng.normal(size=prog.grid_shape) for f in prog.in_fields}
+    mk = lambda: lower(prog, workers=w)                 # noqa: E731
+    ideal, routed, fab, _ = _routed_pair(mk, mk().pack_inputs(ins))
+    sep_ideal = sep_routed = 0
+    for op in prog.schedule():
+        solo = StencilProgram(f"solo_{op.name}", [op],
+                              grid_shape=prog.grid_shape, dtype=prog.dtype)
+        solo_ins = {f: rng.normal(size=prog.grid_shape)
+                    for f in solo.in_fields}
+        mk_solo = lambda: lower(solo, workers=w)        # noqa: E731
+        a, b, _, _ = _routed_pair(mk_solo, mk_solo().pack_inputs(solo_ins))
+        sep_ideal += a.cycles
+        sep_routed += b.cycles
+    assert ideal.cycles < sep_ideal and routed.cycles < sep_routed
+    return (ideal.cycles, routed.cycles, sep_ideal, sep_routed,
+            fab.stats()["max_channel_load"])
+
+
+# The integers come from the smoke snapshots of the pre-chip benchmark,
+# reproduced with the vector engine at commit 08b0e3b.
+@pytest.mark.parametrize("counts,pinned", [
+    # (cycles ideal, cycles routed, PE instructions, stall cycles, token hops)
+    pytest.param(lambda: _spec_counts(paper_stencil_1d(n=1200, rx=8), map_1d),
+                 (239, 1886, 313, 3187499, 131693), id="1d"),
+    pytest.param(lambda: _spec_counts(paper_stencil_2d(ny=30, nx=48, r=12),
+                                      map_2d),
+                 (189, 642, 833, 716046, 109809), id="2d"),
+    pytest.param(lambda: _spec_counts(heat_3d(10, 12, 16, dtype="float64"),
+                                      map_3d),
+                 (319, 978, 169, 1151131, 58089), id="3d"),
+    pytest.param(lambda: _program_counts(hdiff_program(48, 64)),
+                 (566, 3317, 249, 8047371, 231149), id="hdiff"),
+    pytest.param(lambda: _program_counts(two_stage_heat(48, 64)),
+                 (567, 3370, 217, 9281674, 203861), id="heat2_pipeline"),
+    pytest.param(lambda: _program_counts(two_stage_heat(96, 128)),
+                 (2311, 12793, 217, 160809767, 841724),
+                 id="large_heat2_pipeline"),
+    # (fused ideal, fused routed, separate ideal, separate routed,
+    #  max channel load)
+    pytest.param(lambda: _fusion_counts(hdiff_program(24, 32)),
+                 (204, 1668, 629, 4251, 9), id="hdiff_fused"),
+    pytest.param(lambda: _fusion_counts(two_stage_heat(24, 32)),
+                 (202, 1411, 398, 3078, 8), id="heat2_pipeline_fused"),
+])
+def test_simulated_counts_are_pinned(counts, pinned):
+    assert counts() == pinned
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,9 @@
 Covers the subsystem contract end to end: canonical config hashing (the
 cache key), roofline/feasibility pruning with recorded reasons, budgeted
 vector-engine evaluation with failure capture (deadlocks, fabric overflow),
-Pareto-front soundness, the always-measured analytical baseline, and the
-persistent eval cache that makes ci.sh reruns free.
+Pareto-front soundness, the always-measured analytical baseline, the
+persistent eval cache that makes reruns free, and the pinned fronts of three
+routed searches.
 """
 import json
 
@@ -236,6 +237,9 @@ def test_explore_records_deadlock_as_failure():
     reasons3 = [f["reason"] for f in res3.failures]
     assert any(r.startswith("deadlock") for r in reasons3), reasons3
     assert res3.stats["static_pruned"] == 0
+    # the gate rejects exactly the configs the engine finds doomed
+    assert res.stats["static_pruned"] == sum(
+        r.startswith(("deadlock", "timeout")) for r in reasons3)
     # and the gate never changes the search outcome
     assert sorted(p.objectives() for p in res3.points) == \
         sorted(p.objectives() for p in res.points)
@@ -389,3 +393,48 @@ def test_explore_timeout_not_poisoned_across_budgets(tmp_path):
                       cache=EvalCache(cache_path))
     assert healthy.points and not healthy.failures
     assert healthy.stats["n_measured"] == len(healthy.points)
+
+
+# ---------------------------------------------------------------------------
+# pinned fronts: routed searches on the 16x16 mesh, no cache
+# ---------------------------------------------------------------------------
+def _heat2d_search():
+    heat = heat_2d(24, 48, dtype="float64")
+    tiles = tuple(t for t in tile_candidates(heat, (2048, 8192))
+                  if t is not None)
+    return heat, 2, SpaceOptions(temporal=(1, 2),
+                                 capacities=("auto", "unbounded"),
+                                 tiles=(None,) + tiles,
+                                 fabrics=((16, 16, "mesh"),),
+                                 place_seeds=(0, 1))
+
+
+def _star3d_search():
+    return star_3d(10, 12, 16), 1, SpaceOptions(
+        workers=(1, 2, 4, 8), capacities=("auto",),
+        fabrics=((16, 16, "mesh"),), place_seeds=(0,))
+
+
+def _hdiff_search():
+    from repro.program import hdiff_program
+    return hdiff_program(24, 32), 1, SpaceOptions(
+        workers=(2, 4, 8), capacities=("auto", "unbounded"),
+        fabrics=((16, 16, "mesh"),), place_seeds=(0,))
+
+
+# The integers come from the smoke snapshot of the pre-chip benchmark's
+# tuner fronts, reproduced at commit 08b0e3b.
+@pytest.mark.parametrize("search,analytic,best", [
+    # (cycles, PEs, max channel load) of the analytical baseline and the best
+    pytest.param(_heat2d_search, (3276, 23, 6), (906, 59, 6), id="heat2d"),
+    pytest.param(_star3d_search, (2978, 28, 8), (1418, 61, 8), id="star_3d"),
+    pytest.param(_hdiff_search, (1614, 51, 9), (1614, 51, 9), id="hdiff"),
+])
+def test_tuner_front_is_pinned(search, analytic, best):
+    target, timesteps, options = search()
+    res = explore(target, CGRA, options=options,
+                  budget=Budget(routed_finalists=4),
+                  workload_timesteps=timesteps, verify=True)
+    assert_non_dominated(res.front, key=EvalPoint.objectives)
+    assert res.analytic.objectives() == analytic
+    assert res.best().objectives() == best

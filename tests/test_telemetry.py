@@ -15,8 +15,7 @@ Three contracts, each load-bearing:
   timestamps).
 
 Plus the satellites: SimDeadlock stall-attribution diagnostics, tuner
-search spans, EvalCache.stats() replay hits, benchmarks/run.py per-case
-error isolation + nonzero exit, and the bench_diff comparator.
+search spans and EvalCache.stats() replay hits.
 """
 import json
 
@@ -387,236 +386,6 @@ def test_eval_cache_stats_counts_failure_replay(tmp_path):
     assert c2.get("good") and c2.get("bad") and c2.get("gone") is None
     assert c2.stats() == {"hits": 2, "misses": 1, "failures_replayed": 1,
                           "entries": 2}
-
-
-# ---------------------------------------------------------------------------
-# benchmarks/run.py error isolation + exit status (satellite)
-# ---------------------------------------------------------------------------
-def test_run_py_isolates_case_failures(tmp_path, monkeypatch):
-    from benchmarks import run as bench_run
-
-    calls = []
-
-    def boom(cases, name, *a, **kw):
-        calls.append(name)
-        if name == "2d":
-            raise RuntimeError("injected 2d failure")
-        cases[name] = {"cycles_ideal": 1}
-
-    monkeypatch.setattr(bench_run, "_artifact_case", boom)
-    cases, errors = bench_run.artifact_cases(True, "vector")
-    assert calls == ["1d", "2d", "3d"]           # later cases still ran
-    assert set(cases) == {"1d", "3d"}
-    assert list(errors) == ["2d"]
-    assert "injected 2d failure" in errors["2d"]
-
-    # the writer persists the partial artifact, then propagates the failure
-    path = tmp_path / "a.json"
-    with pytest.raises(RuntimeError, match="1 case\\(s\\) failed"):
-        bench_run._write_snapshot(str(path), "bench_pr2/v1", True, None,
-                                  (cases, errors), engine="vector")
-    art = json.loads(path.read_text())
-    assert set(art["cases"]) == {"1d", "3d"}
-    assert "injected 2d failure" in art["errors"]["2d"]
-
-    # and main() turns it into a nonzero exit
-    with pytest.raises(SystemExit) as ei:
-        bench_run.main(["--artifact", str(tmp_path / "b.json"),
-                        "--smoke", "--artifact-only", "--engine", "vector"])
-    assert ei.value.code == 1
-    assert "errors" in json.loads((tmp_path / "b.json").read_text())
-
-
-def test_run_py_all_good_exits_zero(tmp_path, monkeypatch):
-    from benchmarks import run as bench_run
-
-    def ok(cases, name, *a, **kw):
-        cases[name] = {"cycles_ideal": 1}
-
-    monkeypatch.setattr(bench_run, "_artifact_case", ok)
-    bench_run.main(["--artifact", str(tmp_path / "a.json"),
-                    "--smoke", "--artifact-only"])   # no SystemExit
-    art = json.loads((tmp_path / "a.json").read_text())
-    assert set(art["cases"]) == {"1d", "2d", "3d"}
-    assert "errors" not in art
-
-
-# ---------------------------------------------------------------------------
-# bench_diff (satellite)
-# ---------------------------------------------------------------------------
-def _pr4_case(**over):
-    base = {"cycles_ideal": 189, "cycles_routed": 642,
-            "pe_instructions": 833, "stall_cycles": 716046,
-            "token_hops": 9000, "vector_wall_s": 0.30}
-    base.update(over)
-    return base
-
-
-def _art(tmp_path, name, cases, schema="bench_pr4/v1", config="smoke",
-         **extra):
-    p = tmp_path / name
-    p.write_text(json.dumps({"schema": schema, "config": config,
-                             "cases": cases, **extra}))
-    return str(p)
-
-
-def test_bench_diff(tmp_path, capsys):
-    from benchmarks.bench_diff import main as bd
-    base = {"2d": _pr4_case()}
-    a = _art(tmp_path, "a.json", base)
-    assert bd([a, a]) == 0
-
-    # integer counters are exact; float walls get the tolerance band
-    drift = _art(tmp_path, "b.json", {"2d": _pr4_case(cycles_routed=643)})
-    assert bd([a, drift]) == 1
-    out = capsys.readouterr().out
-    assert "deterministic counter changed 642 -> 643" in out
-
-    wall_ok = _art(tmp_path, "c.json",
-                   {"2d": _pr4_case(vector_wall_s=0.36)})
-    assert bd([a, wall_ok]) == 0
-    wall_bad = _art(tmp_path, "d.json",
-                    {"2d": _pr4_case(vector_wall_s=3.0)})
-    assert bd([a, wall_bad]) == 1
-
-    # config mismatch (smoke vs full) is never comparable
-    full = _art(tmp_path, "e.json", base, config="full")
-    assert bd([a, full]) == 1
-
-    # partial artifacts (errors key) fail the gate
-    part = _art(tmp_path, "f.json", base, errors={"3d": "boom"})
-    assert bd([a, part]) == 1
-
-
-def test_bench_diff_intersection_and_allowlist(tmp_path, capsys):
-    """Keys on one side only warn (schema growth); required counters
-    missing on either side fail; volatile pr5 structure is skipped."""
-    from benchmarks.bench_diff import main as bd
-    a = _art(tmp_path, "a.json", {"2d": _pr4_case()})
-    grown = _art(tmp_path, "g.json",
-                 {"2d": _pr4_case(bottleneck="network-bound",
-                                  stall_breakdown={"input_starved": 3})})
-    assert bd([a, grown]) == 0               # new keys: warn, not fail
-    out = capsys.readouterr().out
-    assert "only in NEW" in out
-    assert bd([a, grown, "--strict"]) == 1   # --strict promotes to fail
-
-    # losing a required counter is a broken refresh, not schema evolution
-    lost_case = _pr4_case()
-    del lost_case["cycles_routed"]
-    lost = _art(tmp_path, "l.json", {"2d": lost_case})
-    assert bd([a, lost]) == 1
-    out = capsys.readouterr().out
-    assert "required counter missing in NEW" in out
-
-    # pr5-style artifacts: nested dotted required keys; front/stats are
-    # volatile and must not fail even when completely different
-    def pr5_case(cycles=1618, front=()):
-        return {"analytic": {"cycles": 1700, "pes": 60, "cached": False},
-                "best": {"cycles": cycles, "pes": 51,
-                         "max_channel_load": 9},
-                "front": list(front), "n_points": len(front),
-                "stats": {"wall_s": 0.8, "n_measured": 8}}
-    p5a = _art(tmp_path, "p5a.json", {"hdiff": pr5_case(front=[{"a": 1}])},
-               schema="bench_pr5/v1")
-    p5b = _art(tmp_path, "p5b.json", {"hdiff": pr5_case(front=[{"b": 2}])},
-               schema="bench_pr5/v1")
-    assert bd([p5a, p5b]) == 0
-    p5worse = _art(tmp_path, "p5w.json", {"hdiff": pr5_case(cycles=1800)},
-                   schema="bench_pr5/v1")
-    assert bd([p5a, p5worse]) == 1
-    out = capsys.readouterr().out
-    assert "best.cycles" in out
-
-
-def test_bench_diff_trend_gate(tmp_path, capsys):
-    """Trend mode: fail only when worse than every one of the last N;
-    blessed regressions warn instead of re-firing forever."""
-    from benchmarks.bench_diff import main as bd
-    from repro.telemetry.metrics import append_history, case_records
-
-    hist = str(tmp_path / "hist.jsonl")
-
-    def art_for(cycles):
-        return {"schema": "bench_pr4/v1", "config": "smoke",
-                "cases": {"2d": _pr4_case(cycles_routed=cycles)}}
-
-    # empty history: first run seeds the trend (warn, exit 0)
-    new = _art(tmp_path, "n.json", {"2d": _pr4_case(cycles_routed=650)})
-    assert bd([new, "--trend", "3", "--history", hist]) == 0
-    assert "seeds the trend" in capsys.readouterr().out
-
-    for c in (642, 650, 645):
-        append_history(hist, case_records(art_for(c), ts=1000.0))
-
-    # equal to the most recent -> clean pass
-    ok = _art(tmp_path, "ok.json", {"2d": _pr4_case(cycles_routed=645)})
-    assert bd([ok, "--trend", "3", "--history", hist]) == 0
-    # within the envelope (650 was blessed earlier) -> warn, pass
-    within = _art(tmp_path, "w.json", {"2d": _pr4_case(cycles_routed=648)})
-    assert bd([within, "--trend", "3", "--history", hist]) == 0
-    assert "within envelope" in capsys.readouterr().out
-    # injected regression: worse than max(last 3) -> fail
-    bad = _art(tmp_path, "bad.json", {"2d": _pr4_case(cycles_routed=651)})
-    assert bd([bad, "--trend", "3", "--history", hist]) == 1
-    assert "regression 651 > max(last 3) = 650" in capsys.readouterr().out
-    # the window is honest: last 3 of a longer history
-    append_history(hist, case_records(art_for(700), ts=1001.0))
-    assert bd([bad, "--trend", "3", "--history", hist]) == 0
-
-
-def test_history_unknown_record_shapes_skip_cleanly(tmp_path, capsys,
-                                                    monkeypatch):
-    """Satellite: history records with an unknown version or a partial
-    payload (e.g. a throughput record without ``counters``) must skip with
-    a named warning in the observatory report and the overhead gate —
-    never a KeyError/TypeError."""
-    import benchmarks.observatory as obs
-    import benchmarks.overhead_check as oc
-    from repro.telemetry.metrics import (append_history, case_records,
-                                         record_problem, trend_values)
-
-    hist = str(tmp_path / "hist.jsonl")
-    art = {"schema": "overhead/v1", "config": "smoke",
-           "cases": {"2d_routed_vector": {"cycles": 716, "wall_s": 0.31,
-                                          "engine": "vector", "repeats": 2}}}
-    append_history(hist, case_records(art, source="overhead_check.py"))
-    with open(hist, "a") as f:
-        for bad in (
-                {"v": 99, "schema": "overhead/v1", "config": "smoke",
-                 "case": "2d_routed_vector", "counters": {},
-                 "walls": {"wall_s": 9.9}},          # future version
-                {"v": 1, "schema": "bench_pr9x/v0", "config": "smoke",
-                 "case": "sweep",
-                 "throughput": {"cfg_per_s": 100.0}},  # payload-less
-                {"v": 1, "schema": "overhead/v1", "config": "smoke",
-                 "case": "2d_routed_vector", "counters": None,
-                 "walls": None}):                    # non-mapping payload
-            f.write(json.dumps(bad) + "\n")
-
-    assert record_problem({"v": 1, "counters": {}, "walls": {}}) is None
-    assert record_problem({"v": 99}) == "unknown history version 99"
-    assert record_problem({"v": 1}) == "no counters/walls payload"
-    assert record_problem({"v": 1, "counters": None}) \
-        == "'counters' is not a mapping"
-    # trend_values itself tolerates non-mapping payloads (version filtering
-    # is the consumers' job, via record_problem)
-    from repro.telemetry.metrics import load_history
-    assert trend_values(load_history(hist), "wall_s",
-                        kind="walls") == [0.31, 9.9]
-
-    assert obs.main(["report", "--history", hist]) == 0
-    out = capsys.readouterr().out
-    assert "WARNING" in out and "unknown history version 99" in out
-    assert "no counters/walls payload" in out
-    assert "'counters' is not a mapping" in out
-
-    monkeypatch.setattr(oc, "measure", lambda repeats: (0.30, 716))
-    assert oc.main(["--history", hist, "--no-append"]) == 0
-    out = capsys.readouterr().out
-    assert "WARNING" in out and "skipped 2 history record(s)" in out
-    # the valid record still anchors the envelope (median of one = 0.31)
-    assert "median of last 1 = 0.3100" in out
 
 
 def test_stall_summary_and_report_crash_proofing(rng):
