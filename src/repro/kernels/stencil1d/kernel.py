@@ -20,8 +20,11 @@ Two compute formulations:
     throughput; wins once the fused stencil turns compute-bound (see
     EXPERIMENTS.md §Perf).
 
-Grid requirements (enforced by ops.py): N % block_n == 0, B % block_b == 0,
-r * timesteps <= block_n.
+Grid requirements (ops.py pads to meet them): N % block_n == 0,
+B % block_b == 0, r * timesteps <= block_n.  The kernel takes the signal's
+true length as ``extent``: inputs at or past it read as zero and outputs
+outside ``[r·T, extent - r·T)`` are written as zero, so no pass outside the
+kernel masks the signal.
 """
 from __future__ import annotations
 
@@ -42,8 +45,9 @@ def _ext_positions(j, block_n: int, halo: int):
 
 def _masked_ext(prev, cur, nxt, j, *, block_n: int, halo: int, n: int,
                 acc_dtype):
-    """Assemble the haloed VMEM workspace; zero positions outside [0, n)
-    (this also erases the garbage the clamped edge views bring in)."""
+    """Assemble the haloed VMEM workspace; zero positions outside [0, n),
+    n the true length (this also erases the padding and the garbage the
+    clamped edge views bring in)."""
     ext = jnp.concatenate(
         [prev[:, -halo:], cur[:, :], nxt[:, :halo]], axis=1).astype(acc_dtype)
     pos = _ext_positions(j, block_n, halo)
@@ -111,14 +115,19 @@ def make_band(coeffs: tuple[float, ...], in_w: int, out_w: int) -> np.ndarray:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("coeffs", "timesteps", "block_b", "block_n", "variant",
-                     "interpret"))
+    static_argnames=("coeffs", "timesteps", "block_b", "block_n", "extent",
+                     "variant", "interpret"))
 def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
                      timesteps: int = 1, block_b: int = 8,
-                     block_n: int = 512, variant: str = "vpu",
+                     block_n: int = 512, extent: int,
+                     variant: str = "vpu",
                      interpret: bool = False) -> jax.Array:
     """x: (B, N) -> (B, N). Requires B % block_b == 0, N % block_n == 0,
-    radius * timesteps <= block_n (ops.py pads to satisfy these)."""
+    radius * timesteps <= block_n (ops.py pads to satisfy these).
+
+    ``extent`` is the signal's true length: x holds it at its origin.
+    Inputs at or past it read as zero and outputs outside ``[r·T, extent -
+    r·T)`` are written as zero, so this is the only boundary mask."""
     b, n = x.shape
     r = (len(coeffs) - 1) // 2
     halo = r * timesteps
@@ -143,7 +152,7 @@ def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
     if variant == "vpu":
         body = functools.partial(
             _vpu_body, coeffs=coeffs, timesteps=timesteps, block_n=block_n,
-            n=n, out_dtype=x.dtype)
+            n=extent, out_dtype=x.dtype)
         return pl.pallas_call(
             body, grid=(nb, nn), in_specs=views, out_specs=out_spec,
             out_shape=out_shape, compiler_params=params,
@@ -153,8 +162,8 @@ def stencil1d_pallas(x: jax.Array, coeffs: tuple[float, ...], *,
                                      block_n + 2 * halo - 2 * r))
         band_spec = pl.BlockSpec(band.shape, lambda i, j: (0, 0))
         body = functools.partial(
-            _mxu_body, timesteps=timesteps, radius=r, block_n=block_n, n=n,
-            out_dtype=x.dtype)
+            _mxu_body, timesteps=timesteps, radius=r, block_n=block_n,
+            n=extent, out_dtype=x.dtype)
         return pl.pallas_call(
             body, grid=(nb, nn), in_specs=views + [band_spec],
             out_specs=out_spec, out_shape=out_shape, compiler_params=params,

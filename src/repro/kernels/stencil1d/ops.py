@@ -1,13 +1,11 @@
 """Public entry point for the 1D stencil: planning, padding, backend dispatch.
 
-``stencil1d(x, coeffs)`` accepts any (..., N) array:
-  * flattens leading dims to a batch,
-  * pads batch/length to the planned block multiples (zero padding is
-    harmless: the kernel's position masks ignore out-of-range columns, and
-    padded batch rows are sliced away),
-  * dispatches to the Pallas kernel (TPU, or ``interpret=True`` elsewhere) or
-    the pure-jnp reference (``backend="xla"``), which is also what the LM
-    models use under jit on CPU.
+``stencil1d(x, coeffs)`` accepts any (..., N) array.  The Pallas path is one
+jitted program: it flattens the leading dims to a batch, pads batch and
+length to the planned block multiples, runs the kernel (TPU, or
+``interpret=True`` elsewhere), which alone masks the boundary against the
+true length, and slices the padding away.  ``backend="xla"`` runs the
+pure-jnp reference, which is also what the LM models use under jit on CPU.
 """
 from __future__ import annotations
 
@@ -67,42 +65,34 @@ def stencil1d(x: jax.Array, coeffs: tuple[float, ...], *,
               block: tuple[int, int] | None = None) -> jax.Array:
     """Batched 1D star stencil along the last axis. See ref.py for semantics."""
     coeffs = tuple(float(c) for c in coeffs)
-    r = (len(coeffs) - 1) // 2
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if backend == "xla":
         return stencil1d_ref(x, coeffs, timesteps=timesteps)
 
     interpret = jax.default_backend() != "tpu"
+    return _entry(x, coeffs, timesteps, block, variant, interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("coeffs", "timesteps", "block", "variant",
+                                    "interpret"))
+def _entry(x, coeffs, timesteps, block, variant, interpret):
+    """The whole entry as one program: where the block divides the signal
+    the reshapes are bitcasts and the pad and slice vanish, so the kernel
+    is the only pass over it."""
     lead = x.shape[:-1]
     n = x.shape[-1]
     xb = x.reshape((-1, n))
     batch = xb.shape[0]
-    if block is None:
-        block = plan_1d_blocks(n, batch, r, timesteps, variant=variant)
-    bb, bn = block
+    bb, bn = block or plan_1d_blocks(n, batch, (len(coeffs) - 1) // 2,
+                                     timesteps, variant=variant)
     pb = _next_multiple(batch, bb) - batch
     pn = _next_multiple(n, bn) - n
-    xp = jnp.pad(xb, ((0, pb), (0, pn)))
-    # padded tail columns are masked via the n-argument = true length
-    out = _dispatch(xp, coeffs, timesteps, bb, bn, variant, interpret, n)
+    out = stencil1d_pallas(jnp.pad(xb, ((0, pb), (0, pn))), coeffs,
+                           timesteps=timesteps, block_b=bb, block_n=bn,
+                           extent=n, variant=variant, interpret=interpret)
     return out[:batch, :n].reshape(*lead, n)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("coeffs", "timesteps", "bb", "bn",
-                                    "variant", "interpret", "true_n"))
-def _dispatch(xp, coeffs, timesteps, bb, bn, variant, interpret, true_n):
-    # The kernel masks by padded length; re-mask by the true length so padded
-    # columns cannot contribute (they're zero anyway) and outputs beyond
-    # true_n - halo are dropped.
-    y = stencil1d_pallas(xp, coeffs, timesteps=timesteps, block_b=bb,
-                         block_n=bn, variant=variant, interpret=interpret)
-    r = (len(coeffs) - 1) // 2
-    halo = r * timesteps
-    idx = jnp.arange(xp.shape[-1])
-    valid = (idx >= halo) & (idx < true_n - halo)
-    return jnp.where(valid, y, 0).astype(y.dtype)
 
 
 def stencil1d_from_spec(x: jax.Array, spec: StencilSpec, **kw) -> jax.Array:

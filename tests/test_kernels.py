@@ -46,6 +46,26 @@ def test_stencil1d_sweep(rng, b, n, r, t, variant, dtype):
                                np.asarray(yr, np.float32), atol=TOL[dtype])
 
 
+@pytest.mark.parametrize("shape", [(5000,), (2, 1000), (2, 3, 384)])
+def test_stencil1d_entry_is_one_dispatch(rng, shape):
+    """Reshape, pad, kernel and slice are one jitted program: a call
+    enqueues it once and runs nothing eagerly around it, and inside an
+    outer jit it still gives the oracle's answer."""
+    coeffs = (0.1, 0.2, 0.4, 0.2, 0.1)
+
+    def fn(x):
+        return stencil1d(x, coeffs, timesteps=2, backend="pallas")
+
+    x = _mk(rng, shape, "float32")
+    jaxpr = jax.make_jaxpr(fn)(x)
+    assert [e.primitive.name for e in jaxpr.eqns] == ["jit"]
+    assert jaxpr.out_avals[0].shape == shape
+    np.testing.assert_allclose(np.asarray(jax.jit(fn)(x)),
+                               np.asarray(stencil1d_ref(x, coeffs,
+                                                        timesteps=2)),
+                               atol=TOL["float32"])
+
+
 def test_stencil1d_block_planner():
     bb, bn = plan_1d_blocks(n=194400, batch=1, radius=8, timesteps=4)
     assert bn % 128 == 0 and bn >= 8 * 4

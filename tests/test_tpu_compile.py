@@ -63,6 +63,31 @@ def test_stencil1d_paper_grid_compiles(one_chip, on_tpu, batch, timesteps,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("shape,timesteps", [
+    ((194400,), 1), ((8, 194400), 4), ((196608,), 1)],
+    ids=["signal", "batch8-T4", "aligned"])
+def test_stencil1d_entry_is_one_program(one_chip, on_tpu, shape, timesteps):
+    """The entry compiles to one program holding one kernel and no copy,
+    and the kernel is the only boundary mask: no select or and op
+    anywhere in the module (XLA would move a second mask past the slice,
+    so its length says nothing).  The paper's 194,400 points are no
+    multiple of the lane-aligned block, so the pad and slice stay, and at
+    that length a rank-1 signal's reshapes in and out are relayouts (the
+    way out fuses with the slice).  Where the block divides the signal,
+    nothing but the kernel passes over it."""
+    from repro.kernels.stencil1d.ops import stencil1d
+    spec = paper_stencil_1d(dtype="float32")
+    text = _compile(lambda x: stencil1d(x, spec.coeffs[0],
+                                        timesteps=timesteps),
+                    shape, one_chip).as_text()
+    ops = set(re.findall(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[[\d,]*\]\S* "
+                         r"([\w-]+)\(", text, re.M))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not ops & {"copy", "select", "and"}, ops
+    if shape[-1] % 4096 == 0:
+        assert ops == {"parameter", "bitcast", "custom-call"}, ops
+
+
 @pytest.mark.parametrize("spec,timesteps", [
     (paper_stencil_2d(dtype="float32"), 1),
     (paper_stencil_2d(dtype="float32"), 4),
